@@ -40,20 +40,6 @@ from repro.core.histogram import build_exact_padded_batched
 SCHEMA = "bench_ingest/v1"
 
 
-def _jit_cache_size(fn) -> int | None:
-    try:
-        return fn._cache_size()
-    except AttributeError:
-        return None
-
-
-def _compiles(fn, before: int | None) -> int | None:
-    after = _jit_cache_size(fn)
-    if before is None or after is None:
-        return None
-    return after - before
-
-
 def _rates(parts: dict[int, np.ndarray], seconds: float) -> dict:
     values = int(sum(v.size for v in parts.values()))
     return {
@@ -67,25 +53,25 @@ def run_per_partition(parts, T, sample: int) -> dict:
     """Legacy Summarizer: one shape-keyed ``build_exact`` per partition."""
     sub = dict(list(parts.items())[:sample])
     store = HistogramStore(num_buckets=T)
-    before = _jit_cache_size(build_exact)
+    before = build_exact._cache_size()
     t0 = time.perf_counter()
     for pid, v in sub.items():
         h = build_exact(jnp.asarray(v), min(T, v.shape[0]))
         h.sizes.block_until_ready()
         store.ingest_summary(pid, h)
     out = _rates(sub, time.perf_counter() - t0)
-    out["compiles"] = _compiles(build_exact, before)
+    out["compiles"] = build_exact._cache_size() - before
     out["measured_partitions"] = len(sub)
     return out
 
 
 def run_batched(parts, T) -> tuple[dict, HistogramStore]:
     store = HistogramStore(num_buckets=T)
-    before = _jit_cache_size(build_exact_padded_batched)
+    before = build_exact_padded_batched._cache_size()
     t0 = time.perf_counter()
     store.ingest_many(parts)
     out = _rates(parts, time.perf_counter() - t0)
-    out["compiles"] = _compiles(build_exact_padded_batched, before)
+    out["compiles"] = build_exact_padded_batched._cache_size() - before
     out["dispatch_shapes"] = len(store.summarize_shapes)
     return out, store
 
@@ -194,10 +180,7 @@ def main(
             "max_n": int(max_n),
             "bound": compile_bound,
             "batched_compiles": batched["compiles"],
-            "bounded": (
-                batched["compiles"] is None
-                or batched["compiles"] <= compile_bound
-            ),
+            "bounded": batched["compiles"] <= compile_bound,
         },
         "t_node": tnode,
     }

@@ -47,9 +47,11 @@ from benchmarks import replication as replication_bench
 from benchmarks import retention as retention_bench
 from benchmarks import roofline_report
 from benchmarks import serving as serving_bench
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="all")
     args = ap.parse_args()

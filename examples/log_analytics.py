@@ -2,8 +2,8 @@
 interval histograms — Summarizer/Merger (paper §5, Fig. 13) on JAX.
 
 A month of synthetic web-server latency logs is ingested day by day (the
-scheduled Summarizer job — here through the *Pallas tile-sort path*, i.e.
-exactly what runs per-device on TPU).  Then on-demand Merger queries answer
+scheduled Summarizer job — the served path's exact batched summarizer,
+the same program that runs on a TPU).  Then on-demand Merger queries answer
 the paper's motivating questions:
 
   * histogram of any time interval (last week / Christmas season),
@@ -29,16 +29,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import HistogramStore, TenantRegistry, quantile, range_count
-from repro.kernels import summarize_pallas
 
 
 def synth_day(rng, day: int, base: int = 65_536) -> np.ndarray:
     """Log-normal latency with a weekly cycle and holiday surge.
 
-    Days have ragged lengths (real traffic is never tile-aligned) — the
-    Pallas Summarizer masks the sentinel-padded tail tile.
+    Days have ragged lengths (real traffic is never power-of-two sized) —
+    the Summarizer masks the sentinel-padded tail of each partition.
     """
-    n = base + int(rng.integers(0, max(1, base // 16)))  # not tile-aligned
+    n = base + int(rng.integers(0, max(1, base // 16)))  # not a power of two
     scale = 1.0 + 0.25 * (day % 7 in (5, 6)) + 0.6 * (day >= 24)
     return (rng.lognormal(-1.8, 0.55, size=n) * scale).astype(np.float32)
 
@@ -52,14 +51,11 @@ def main(smoke: bool = False) -> None:
     store = HistogramStore(num_buckets=T)
     raw = {}
 
-    print("== Summarizer (daily, offline — Pallas tile-sort path) ==")
+    print("== Summarizer (daily, offline — batched exact summaries) ==")
     for day in range(31):
         v = synth_day(rng, day, day_n)
         raw[day] = v
-        h = summarize_pallas(
-            jnp.asarray(v), tile_len=4096, T_tile=512, T_out=T
-        )
-        store.ingest_summary(day, h)
+        store.ingest(day, v)
     total = sum(len(v) for v in raw.values())
     print(f"ingested 31 ragged days ({total:,} records) "
           f"→ {31*(T*2+1)*4/1e6:.1f} MB of summaries (vs "

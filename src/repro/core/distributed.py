@@ -118,20 +118,10 @@ def hierarchical_device_summary(
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    # Replication checking is off (check_vma / legacy check_rep) because the
-    # merged output is replicated by construction (post-all_gather).
-    if hasattr(jax, "shard_map"):  # public API from jax 0.5 on
-        return jax.shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    # Replication checking is off because the merged output is replicated
+    # by construction (post-all_gather).
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 

@@ -4,8 +4,9 @@ tile_sort     — bitonic sorting network over VMEM tiles (the Summarizer sort)
 bucket_count  — streaming boundary-comparison bucket counting (validation/query)
 merge_cut     — fused Algorithm-1 merge: kv-sort + prefix-sum + rank-select
 
-Validated on CPU with ``interpret=True`` against the ``ref.py`` oracles;
-``interpret=False`` on real TPUs.
+Validated on CPU in the Pallas interpreter against the ``ref.py`` oracles.
+The ``interpret`` flag defaults to ``None``: interpreter on the CPU,
+compiled elsewhere — where today they fail to lower (kernels/ops.py).
 """
 from repro.kernels.bucket_count import cumulative_counts_pallas
 from repro.kernels.merge_cut import merge_pallas

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tile_sort import resolve_interpret
+
 __all__ = ["bucket_count_kernel", "cumulative_counts_pallas"]
 
 LANE = 128  # TPU vector lane width; last dim of every VMEM tile
@@ -63,7 +65,7 @@ def cumulative_counts_pallas(
     boundaries: jax.Array,
     *,
     block_rows: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Cumulative ``< b_j`` counts of ``x`` (any shape) + ``== b_T`` count.
 
@@ -90,6 +92,6 @@ def cumulative_counts_pallas(
         ],
         out_specs=pl.BlockSpec((T1 + 1,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((T1 + 1,), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xt, b)
     return out

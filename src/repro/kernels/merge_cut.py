@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tile_sort import _bitonic_kv
+from repro.kernels.tile_sort import _bitonic_kv, resolve_interpret
 
 __all__ = ["merge_cut_kernel", "merge_pallas"]
 
@@ -88,7 +88,7 @@ def merge_pallas(
     sizes: jax.Array,
     beta: int,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Merge stacked summaries ``boundaries (k, T+1)``, ``sizes (k, T)``.
 
@@ -130,6 +130,6 @@ def merge_pallas(
             jax.ShapeDtypeStruct((beta + 1,), jnp.float32),
             jax.ShapeDtypeStruct((beta,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(flat, mass, targets, last)
     return bo, so

@@ -2,8 +2,13 @@
 
 ``summarize_pallas`` is the full TPU Summarizer pipeline: bitonic-sort VMEM
 tiles → per-tile exact histograms → merge (optionally via the fused merge
-kernel).  On CPU the kernels run under ``interpret=True`` (Python-level
-execution of the kernel body); on TPU set ``interpret=False``.
+kernel).  ``interpret=None`` (the default) runs the kernel bodies in the
+Pallas interpreter on the CPU and compiles them on any other backend
+(``tile_sort.resolve_interpret``).  None of them lowers for the TPU yet:
+Mosaic has no rule for ``rev`` (the bitonic partner exchange) nor for
+``dynamic_slice`` (``bucket_count_kernel``), so on a TPU these wrappers
+raise ``NotImplementedError`` instead of running.  The served path
+(``core/``) never calls them.
 """
 from __future__ import annotations
 
@@ -32,7 +37,7 @@ def bucket_sizes_pallas(
     boundaries: jax.Array,
     *,
     block_rows: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """True per-bucket counts of ``x`` under ``boundaries`` (validation op)."""
     cum = cumulative_counts_pallas(
@@ -75,7 +80,7 @@ def summarize_pallas(
     tile_len: int = 4096,
     T_tile: int = 256,
     T_out: int = 1024,
-    interpret: bool = True,
+    interpret: bool | None = None,
     fused_merge: bool = True,
 ) -> Histogram:
     """TPU Summarizer: tile-sort kernel + paper-merge of the tile summaries.
@@ -104,7 +109,7 @@ def summarize_pallas(
 
 @functools.partial(jax.jit, static_argnames=("beta", "interpret"))
 def merge_histograms_pallas(
-    stacked: Histogram, beta: int, *, interpret: bool = True
+    stacked: Histogram, beta: int, *, interpret: bool | None = None
 ) -> Histogram:
     """Fused Merger kernel over stacked summaries (k, T+1)/(k, T)."""
     b, s = merge_pallas(

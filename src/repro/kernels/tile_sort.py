@@ -10,7 +10,9 @@ own merge theorem* combine per-tile exact histograms into the device summary
 one level down the memory hierarchy: HDFS partition → HBM shard → VMEM tile.
 
 The compare-exchange partner ``i ^ j`` is realized as a reshape + reverse of
-the trailing block pair — a relayout Mosaic handles — rather than a gather.
+the trailing block pair rather than a gather.  Mosaic has no lowering rule
+for that reverse (``rev``), so these kernels do not compile for the TPU yet
+(tests/test_chip_compile.py records the refusal).
 
 Key-value variant (``tile_sort_kv_kernel``) carries a payload through the
 network (used by the fused merge kernel to keep bucket masses aligned with
@@ -30,9 +32,23 @@ __all__ = [
     "sort_tiles_pallas",
     "sort_kv_pallas",
     "pad_to_tiles",
+    "resolve_interpret",
 ]
 
 LANE = 128
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The ``interpret`` flag every kernel wrapper passes to ``pallas_call``.
+
+    ``None`` (the wrappers' default) runs the kernel body in the Pallas
+    interpreter on the CPU and compiles it on any other backend, so a TPU
+    caller gets the Mosaic lowering — or its error — and never a silent
+    interpreter run.
+    """
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
 
 
 def pad_to_tiles(flat: jax.Array, tile_len: int) -> jax.Array:
@@ -129,7 +145,7 @@ def tile_sort_kv_kernel(k_ref, v_ref, ko_ref, vo_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sort_tiles_pallas(xt: jax.Array, *, interpret: bool = True) -> jax.Array:
+def sort_tiles_pallas(xt: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Sort each row of ``(tiles, tile_len)`` independently.
 
     ``tile_len`` must be a power of two and a multiple of 128 (one VMEM tile
@@ -145,14 +161,14 @@ def sort_tiles_pallas(xt: jax.Array, *, interpret: bool = True) -> jax.Array:
         in_specs=[pl.BlockSpec((1, rows, LANE), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, rows, LANE), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((tiles, rows, LANE), xt.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xr)
     return out.reshape(tiles, tile_len)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sort_kv_pallas(
-    keys: jax.Array, vals: jax.Array, *, interpret: bool = True
+    keys: jax.Array, vals: jax.Array, *, interpret: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """Row-wise key-value sort of ``(tiles, tile_len)`` pairs."""
     tiles, tile_len = keys.shape
@@ -175,6 +191,6 @@ def sort_kv_pallas(
             jax.ShapeDtypeStruct((tiles, rows, LANE), keys.dtype),
             jax.ShapeDtypeStruct((tiles, rows, LANE), vals.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(kr, vr)
     return ko.reshape(tiles, tile_len), vo.reshape(tiles, tile_len)

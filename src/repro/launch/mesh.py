@@ -7,28 +7,18 @@ initialization).
 from __future__ import annotations
 
 import jax
-
-try:  # jax ≥ 0.5 — explicit axis types
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-
-except ImportError:  # older jax: every mesh axis is implicitly Auto
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> jax.sharding.Mesh:

@@ -21,11 +21,13 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke as smoke_cfg
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import init_model
 from repro.serve import Engine, HistogramService, ServeConfig
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

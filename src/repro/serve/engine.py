@@ -210,7 +210,10 @@ class HistogramService:
         #: torn records dropped) — surface these in the serving logs
         self.recovery = self.registry.last_recovery
         #: snapshot-verification report when salvage rebuilt from the WAL
-        self.salvage = self.registry.last_salvage
+        #: (None when the snapshot verified and loaded; the passing report
+        #: stays on ``health()["last_salvage"]``)
+        report = self.registry.last_salvage
+        self.salvage = report if report is not None and not report["ok"] else None
         # standing-query plane, created on first subscribe()
         self._plane: SubscriptionPlane | None = None
         if replicate_to:

@@ -108,10 +108,7 @@ def test_compile_stability_50_random_length_ingests():
     T = 64
     max_n = 8192
     store = HistogramStore(num_buckets=T)
-    try:
-        cache_before = build_exact_padded_batched._cache_size()
-    except AttributeError:  # jax without the introspection hook
-        cache_before = None
+    cache_before = build_exact_padded_batched._cache_size()
     lengths = rng.integers(T, max_n + 1, size=50)
     assert len(set(lengths)) > 20  # the mix really is ragged
     for pid, n in enumerate(lengths):
@@ -122,9 +119,8 @@ def test_compile_stability_50_random_length_ingests():
     assert all(
         n_pad & (n_pad - 1) == 0 for (_, n_pad, _) in store.summarize_shapes
     )
-    if cache_before is not None:
-        compiled = build_exact_padded_batched._cache_size() - cache_before
-        assert compiled <= bound
+    compiled = build_exact_padded_batched._cache_size() - cache_before
+    assert compiled <= bound
     # and the store still answers correctly over the ragged mix
     h, eps = store.query(0, 49, beta=16)
     assert float(np.asarray(h.sizes).sum()) == pytest.approx(lengths.sum())

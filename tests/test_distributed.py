@@ -11,6 +11,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_with_devices(code: str, n: int = 8) -> str:
     env = dict(os.environ)
+    # virtual CPU devices: on a TPU host the child would otherwise reach
+    # for the chip the parent may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run(

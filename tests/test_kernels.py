@@ -122,3 +122,17 @@ def test_summarize_pipeline_bound(tile_len, T_tile):
     err = np.abs(np.asarray(h.sizes) - n / T_tile).max()
     assert err <= 2 * n / T_tile + 2 * n_tiles
     assert float(np.asarray(h.sizes).sum()) == pytest.approx(n)
+
+
+@pytest.mark.parametrize(
+    "backend, flag, want",
+    [("cpu", None, True), ("tpu", None, False), ("tpu", True, True),
+     ("cpu", False, False)],
+)
+def test_interpret_default_follows_backend(monkeypatch, backend, flag, want):
+    """The wrappers' interpret=None runs the interpreter only on the CPU:
+    a TPU caller gets the Mosaic lowering, never a silent interpreter."""
+    from repro.kernels import tile_sort
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert tile_sort.resolve_interpret(flag) is want

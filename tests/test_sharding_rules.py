@@ -9,10 +9,7 @@ from repro.sharding import Rules
 
 def fake_mesh(shape=(16, 16), axes=("data", "model")):
     # Rules only reads mesh.shape / axis_names — an abstract mesh suffices.
-    try:  # jax ≥ 0.5: AbstractMesh(shape, axis_names)
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(shape, axes)
 
 
 def test_train_rules_dense():
