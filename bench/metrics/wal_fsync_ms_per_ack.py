@@ -1,0 +1,12 @@
+"""WAL fsync time (``wal_stats()["fsync_seconds_total"]``) per ingest call
+acked in the window, in milliseconds."""
+
+
+def snapshot(svc):
+    return (svc.wal_stats() or {}).get("fsync_seconds_total", 0.0)
+
+
+def read(run, before, after):
+    s = run.stats.get("ingest_many")
+    calls = 0 if s is None else sum(1 for r in s.requests if r.work)
+    return None if calls == 0 else 1e3 * (after - before) / calls

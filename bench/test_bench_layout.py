@@ -1,0 +1,150 @@
+"""The benchmark is driven by data: ``BENCHMARK.json`` is well formed,
+every name it holds has its file, a configuration, mix and metric
+dropped in as new files are found with no edit to an existing one, and the
+command refuses to run off the chip."""
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+BENCH = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(BENCH)
+sys.path.insert(0, BENCH)
+
+import generator  # noqa: E402
+import harness  # noqa: E402
+import tiny  # noqa: E402
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+
+
+@pytest.fixture(scope="module")
+def spec():
+    return harness.load_spec(REPO)
+
+
+def test_benchmark_json_is_well_formed(spec):
+    assert set(spec) == {"command", "paths", "run_seconds", "configs", "workloads",
+                         "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
+    assert spec["paths"] == ["bench"] and spec["command"][1].startswith("bench/")
+    assert 1 <= spec["run_seconds"] <= 51
+    configs = {c["name"]: c for c in spec["configs"]}
+    for c in spec["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        with open(os.path.join(REPO, c["file"])) as f:
+            body = json.load(f)
+        assert c["file"].startswith("bench/") and set(c["reduced"]) == set(body["reduced"])
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
+    names = [x["name"] for x in spec["configs"] + spec["workloads"]
+             + spec["end_to_end"] + spec["per_layer"]]
+    assert len(names) == len(set(names)) and all(NAME.match(n) for n in names)
+    for m in spec["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.25 and m["source"] in ("host_clock", "device_trace")
+    cells = {w["name"]: w for w in spec["workloads"]}
+    for w in cells.values():
+        assert w["config"] in configs and w["chips"] in (1, 4) and len(w["why"]) <= 200
+        assert os.path.exists(os.path.join(BENCH, "traffic", f"{w['traffic']}.json"))
+        reported = [m for m in spec["end_to_end"] if w["name"] in m.get("workloads", [w["name"]])]
+        assert "setup_s" in {m["name"] for m in reported} and len(reported) >= 2
+        assert any(w["name"] in m["workloads"] for m in spec["per_layer"])
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+        assert os.path.exists(os.path.join(BENCH, "metrics", f"{m['name']}.py")), m["name"]
+    for m in spec["per_layer"]:
+        assert m["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
+        for cell in m["workloads"]:
+            assert cell in cells
+            assert cell in e2e[m["moves"]].get("workloads", [cell]), (m["name"], cell)
+        if m["name"].endswith("_roofline"):
+            assert m["unit"] == "%"
+    layers = {}
+    for m in spec["per_layer"]:
+        assert "\n" not in m["layer"] and len(m["layer"]) <= 200
+        layers.setdefault(m["layer"].lower(), set()).add(m["layer"])
+    assert all(len(v) == 1 for v in layers.values())
+
+
+def test_new_config_mix_and_metric_are_found_as_files(tmp_path):
+    root = tiny.make_root(str(tmp_path), "cpu")
+    with open(os.path.join(root, "bench", "configs", "tiny-daily.json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="tiny-extra", metrics=2, windows=24)
+    with open(os.path.join(root, "bench", "configs", "tiny-extra.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(root, "bench", "traffic", "scan.json"), "w") as f:
+        json.dump({"preload": "all", "check_answers": 4, "clients": [
+            {"kind": "query", "batch": 3, "span": {"choice": ["windows"]}}]}, f)
+    with open(os.path.join(root, "bench", "metrics", "panels_in_window.py"), "w") as f:
+        f.write("def read(run, before, after):\n    return run.stats['query'].attempted\n")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": "tiny-extra", "source": "a test", "reduced": [],
+                            "file": "bench/configs/tiny-extra.json", "why": "a test"})
+    spec["workloads"].append({"name": "extra.scan", "config": "tiny-extra", "traffic": "scan",
+                              "chips": 1, "why": "a test"})
+    for m in spec["end_to_end"]:
+        if m["name"] == "query_p95_ms":
+            m["workloads"].append("extra.scan")
+    spec["per_layer"].append({"name": "panels_in_window", "unit": "panels", "better": "higher",
+                              "source": "host_clock", "layer": "load generator",
+                              "moves": "query_p95_ms", "workloads": ["extra.scan"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    result, lines = harness.run_cell(root, "extra.scan", 9, 0.3, True, platform="cpu", cache=False)
+    assert result["correct"], lines
+    assert result["metrics"]["panels_in_window"]["value"] == result["attempted"] > 0
+    result, _ = harness.run_cell(root, "extra.scan", 9, 0.3, False, platform="cpu", cache=False)
+    assert set(result["metrics"]) == {"query_p95_ms", "setup_s"}
+
+
+def test_a_device_missing_from_the_peaks_table_fails(tmp_path):
+    root = tiny.make_root(str(tmp_path), "not this device")
+    with pytest.raises(KeyError, match="peaks"):
+        harness.run_cell(root, "logstats.planner", 1, 0.1, False, platform="cpu", cache=False)
+
+
+def test_merge_bytes_counts_the_least_work():
+    sys.path.insert(0, os.path.join(BENCH, "metrics"))
+    import merge_roofline
+
+    # one panel of 3 nodes at T = 4, beta = 2: 3 rows of 5 + 4 floats read,
+    # 3 + 2 floats written
+    assert merge_roofline.merge_bytes(3, 1, 4, 2) == 4 * (3 * 9 + 5)
+    assert merge_roofline.merge_bytes(0, 0, 2032, 254) == 0
+
+
+@pytest.mark.parametrize("lo, hi", [(0, 0), (0, 511), (3, 300), (17, 18), (255, 256), (1, 1022)])
+def test_cover_size_is_the_programs_decomposition(lo, hi):
+    sys.path.insert(0, os.path.join(REPO, "src"))
+    from repro.core.interval_tree import canonical_decomposition
+
+    assert generator.cover_size(lo, hi) == len(canonical_decomposition(lo, hi))
+
+
+def _run(cwd, env_extra):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra)
+    return subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "logstats.planner", "--seed", "1",
+         "--seconds", "1", "--trace", "0"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300,
+    )
+
+
+def test_run_refuses_without_a_tpu(tmp_path):
+    p = _run(REPO, {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert p.returncode == 2 and p.stdout.strip() == ""
+    assert "tpu" in p.stderr
+
+
+def test_run_fails_with_only_the_benchmark_files(tmp_path):
+    shutil.copytree(BENCH, tmp_path / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), tmp_path / "BENCHMARK.json")
+    p = _run(str(tmp_path), {"JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")})
+    assert p.returncode != 0 and p.stdout.strip() == ""
