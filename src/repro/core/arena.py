@@ -92,6 +92,7 @@ import numpy as np
 
 from repro.analysis.witness import OrderedRLock
 from repro.core import faults
+from repro.core.spans import span
 
 __all__ = ["NodeArena"]
 
@@ -157,6 +158,10 @@ class NodeArena:
         # host-side row materializations since construction/reset — the
         # machine-checked "zero-copy" counter (mirrors merge_dispatches)
         self.host_row_copies = 0
+        # whole-plane uploads to the device by :meth:`device`, and their
+        # bytes (boundaries + sizes)
+        self.device_uploads = 0
+        self.device_upload_bytes = 0
 
     # ------------------------------------------------------------ allocation
     def _plane(self, width: int) -> _Plane:
@@ -255,8 +260,12 @@ class NodeArena:
         with self._lock:
             plane = self._planes[width]
             if plane._device_version != plane.version:
-                plane._device = (jnp.asarray(plane.b), jnp.asarray(plane.s))
+                nbytes = plane.b.nbytes + plane.s.nbytes
+                with span("hist.arena.upload", bytes=nbytes):
+                    plane._device = (jnp.asarray(plane.b), jnp.asarray(plane.s))
                 plane._device_version = plane.version
+                self.device_uploads += 1
+                self.device_upload_bytes += nbytes
             return plane._device
 
     # ------------------------------------------------------------- metering

@@ -214,11 +214,14 @@ def _masked_cuts(n: jax.Array, T: int) -> jax.Array:
 
 
 def _build_exact_masked(values, n, num_buckets, count_dtype):
-    sv = jnp.sort(values)  # sentinel pad sorts past every real value
-    n = jnp.asarray(n, jnp.int32)
-    cuts = _masked_cuts(n, num_buckets)
-    boundaries = sv[jnp.minimum(cuts, n - 1)]
-    sizes = jnp.diff(cuts).astype(count_dtype)
+    # named scopes tag the device ops of each phase in a profiler trace
+    with jax.named_scope("summarize.sort"):
+        sv = jnp.sort(values)  # sentinel pad sorts past every real value
+    with jax.named_scope("summarize.cut"):
+        n = jnp.asarray(n, jnp.int32)
+        cuts = _masked_cuts(n, num_buckets)
+        boundaries = sv[jnp.minimum(cuts, n - 1)]
+        sizes = jnp.diff(cuts).astype(count_dtype)
     return Histogram(boundaries=boundaries, sizes=sizes)
 
 
@@ -288,18 +291,21 @@ def merge(histograms: Histogram, beta: int) -> Histogram:
     Vectorized rank-select equivalent of paper Algorithm 1 (see module
     docstring).  Fully jit-able: one sort + cumsum + batched searchsorted.
     """
-    pos, A = pre_histogram(histograms)
-    n = jnp.sum(histograms.sizes)
-    targets = jnp.arange(1, beta, dtype=A.dtype) * (n / beta)
-    cut = jnp.searchsorted(A, targets, side="right")  # (β-1,) in [0, len(A)]
-    interior = pos[cut]
-    boundaries = jnp.concatenate([pos[:1], interior, pos[-1:]])
-    # Cumulative size at each cut: A[cut-1], with A[-1] treated as 0.
-    s_at_cut = jnp.where(cut > 0, A[jnp.maximum(cut - 1, 0)], 0.0)
-    full = jnp.concatenate(
-        [jnp.zeros((1,), A.dtype), s_at_cut, n[None].astype(A.dtype)]
-    )
-    sizes = jnp.diff(full)
+    # named scopes tag the device ops of each phase in a profiler trace
+    with jax.named_scope("merge.presort"):
+        pos, A = pre_histogram(histograms)
+    with jax.named_scope("merge.cut"):
+        n = jnp.sum(histograms.sizes)
+        targets = jnp.arange(1, beta, dtype=A.dtype) * (n / beta)
+        cut = jnp.searchsorted(A, targets, side="right")  # (β-1,) in [0, len(A)]
+        interior = pos[cut]
+        boundaries = jnp.concatenate([pos[:1], interior, pos[-1:]])
+        # Cumulative size at each cut: A[cut-1], with A[-1] treated as 0.
+        s_at_cut = jnp.where(cut > 0, A[jnp.maximum(cut - 1, 0)], 0.0)
+        full = jnp.concatenate(
+            [jnp.zeros((1,), A.dtype), s_at_cut, n[None].astype(A.dtype)]
+        )
+        sizes = jnp.diff(full)
     return Histogram(boundaries=boundaries, sizes=sizes)
 
 

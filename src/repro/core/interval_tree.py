@@ -123,6 +123,7 @@ import numpy as np
 from repro.analysis.witness import OrderedLock
 from repro.core.arena import NodeArena
 from repro.core.histogram import Histogram, merge, next_pow2
+from repro.core.spans import span
 
 __all__ = [
     "TreeNode",
@@ -438,42 +439,45 @@ def _merge_pairs_multi(
         T_in = max(
             max(c0.num_buckets, c1.num_buckets) for _, _, _, c0, c1 in work
         )
-        bs = np.zeros((Q_pad, 2, T_in + 1), np.float32)
-        ss = np.zeros((Q_pad, 2, T_in), np.float32)
-        scatter = []
-        for q, (_, _, _, c0, c1) in enumerate(work):
-            scatter.append(((q, 0), c0))
-            scatter.append(((q, 1), c1))
-        for q in range(Q, Q_pad):  # pad the batch with the last real pair
-            scatter.append(((q, 0), work[-1][3]))
-            scatter.append(((q, 1), work[-1][4]))
-        _scatter_rows(bs, ss, scatter, T_in)
+        with span("hist.pullup.pack"):
+            bs = np.zeros((Q_pad, 2, T_in + 1), np.float32)
+            ss = np.zeros((Q_pad, 2, T_in), np.float32)
+            scatter = []
+            for q, (_, _, _, c0, c1) in enumerate(work):
+                scatter.append(((q, 0), c0))
+                scatter.append(((q, 1), c1))
+            for q in range(Q, Q_pad):  # pad the batch with the last real pair
+                scatter.append(((q, 0), work[-1][3]))
+                scatter.append(((q, 1), work[-1][4]))
+            _scatter_rows(bs, ss, scatter, T_in)
         with _COUNTER_LOCK:
             PULLUP_STATS["dispatches"] += 1
             PULLUP_STATS["pair_merges"] += Q
         bo, so = merge_stacks(bs, ss, T_out)
-        bo, so = np.asarray(bo), np.asarray(so)
+        with span("hist.pullup.wait"):
+            bo, so = np.asarray(bo), np.asarray(so)
         # write merge outputs straight into arena rows: one block alloc per
         # destination arena (a shared arena takes one for ALL tenants)
-        by_arena: dict[int, list[int]] = {}
-        for q, (tree, _, _, _, _) in enumerate(work):
-            by_arena.setdefault(id(tree.arena), []).append(q)
-        for qs in by_arena.values():
-            arena = work[qs[0]][0].arena
-            rows = arena.alloc_block(T_out, bo[qs], so[qs])
-            for q, row in zip(qs, rows):
-                tree, level, i, c0, c1 = work[q]
-                n = c0.n + c1.n
-                t_in = min(c0.num_buckets, c1.num_buckets)
-                tree.nodes[(level, i)] = TreeNode(
-                    arena,
-                    T_out,
-                    row,
-                    T_out,
-                    n,
-                    c0.eps + c1.eps + 2.0 * n / t_in + 4.0,
-                    c0.leaves + c1.leaves,
-                )
+        with span("hist.pullup.write"):
+            by_arena: dict[int, list[int]] = {}
+            for q, (tree, _, _, _, _) in enumerate(work):
+                by_arena.setdefault(id(tree.arena), []).append(q)
+            for qs in by_arena.values():
+                arena = work[qs[0]][0].arena
+                rows = arena.alloc_block(T_out, bo[qs], so[qs])
+                for q, row in zip(qs, rows):
+                    tree, level, i, c0, c1 = work[q]
+                    n = c0.n + c1.n
+                    t_in = min(c0.num_buckets, c1.num_buckets)
+                    tree.nodes[(level, i)] = TreeNode(
+                        arena,
+                        T_out,
+                        row,
+                        T_out,
+                        n,
+                        c0.eps + c1.eps + 2.0 * n / t_in + 4.0,
+                        c0.leaves + c1.leaves,
+                    )
 
 
 def pull_up_trees(work: Sequence[tuple["IntervalTree", set[int]]]) -> None:
@@ -489,28 +493,29 @@ def pull_up_trees(work: Sequence[tuple["IntervalTree", set[int]]]) -> None:
     states = [[tree, set(dirty)] for tree, dirty in work if dirty]
     if not states:
         return
-    for level in range(1, max(tree.levels for tree, _ in states) + 1):
-        entries = []
-        for state in states:
-            tree, parents = state
-            if level > tree.levels:
-                continue
-            parents = {s >> 1 for s in parents}
-            state[1] = parents
-            pairs = [
-                i
-                for i in sorted(parents)
-                if (level - 1, 2 * i) in tree.nodes
-                and (level - 1, 2 * i + 1) in tree.nodes
-            ]
-            pair_set = set(pairs)
-            for i in sorted(parents):
-                if i not in pair_set:
-                    tree._update(level, i)
-            if pairs:
-                entries.append((tree, level, pairs))
-        if entries:
-            _merge_pairs_multi(entries)
+    with span("hist.pullup", trees=len(states)):
+        for level in range(1, max(tree.levels for tree, _ in states) + 1):
+            entries = []
+            for state in states:
+                tree, parents = state
+                if level > tree.levels:
+                    continue
+                parents = {s >> 1 for s in parents}
+                state[1] = parents
+                pairs = [
+                    i
+                    for i in sorted(parents)
+                    if (level - 1, 2 * i) in tree.nodes
+                    and (level - 1, 2 * i + 1) in tree.nodes
+                ]
+                pair_set = set(pairs)
+                for i in sorted(parents):
+                    if i not in pair_set:
+                        tree._update(level, i)
+                if pairs:
+                    entries.append((tree, level, pairs))
+            if entries:
+                _merge_pairs_multi(entries)
 
 
 class IntervalTree:

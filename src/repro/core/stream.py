@@ -121,6 +121,7 @@ from repro.core.arena import NodeArena
 from repro.core.interval_tree import COLLAPSE_MODES, IntervalTree
 from repro.core.retention import RetentionPolicy, StoreStats, policy_from_spec
 from repro.core.scrub import checksum_array, payload_checksums
+from repro.core.spans import span
 from repro.core.workers import IngestPool, PoolStateView, WriteAheadLog
 
 __all__ = ["StoredSummary", "HistogramStore", "atomic_savez"]
@@ -409,45 +410,51 @@ class HistogramStore(PoolStateView):
         holds O(log k_max · log max_n) executables total).  Results are
         bit-identical to the per-partition ``build_exact`` path.
         """
-        out: dict[int, StoredSummary] = {}
-        small: list[tuple[int, np.ndarray]] = []
-        groups: dict[int, list[tuple[int, np.ndarray, int]]] = {}
-        for pid, values in parts.items():
-            v = np.asarray(values).reshape(-1)
-            if v.shape[0] < 1:
-                raise ValueError("cannot summarize an empty partition")
-            if v.shape[0] < self.num_buckets:
-                # tiny partition: summarized exactly at T = n (legacy rule)
-                small.append((int(pid), v))
-            else:
-                padded, n = pad_pow2(v)
-                groups.setdefault(padded.shape[0], []).append(
-                    (int(pid), padded, n)
+        with span("hist.summarize", windows=len(parts)):
+            out: dict[int, StoredSummary] = {}
+            small: list[tuple[int, np.ndarray]] = []
+            groups: dict[int, list[tuple[int, np.ndarray, int]]] = {}
+            with span("hist.summarize.pack"):
+                for pid, values in parts.items():
+                    v = np.asarray(values).reshape(-1)
+                    if v.shape[0] < 1:
+                        raise ValueError("cannot summarize an empty partition")
+                    if v.shape[0] < self.num_buckets:
+                        # tiny partition: summarized exactly at T = n
+                        small.append((int(pid), v))
+                    else:
+                        padded, n = pad_pow2(v)
+                        groups.setdefault(padded.shape[0], []).append(
+                            (int(pid), padded, n)
+                        )
+            for pid, v in small:
+                h = build_exact(jax.numpy.asarray(v), v.shape[0])
+                out[pid] = _make_summary(
+                    pid, v.shape[0], np.asarray(h.boundaries), np.asarray(h.sizes)
                 )
-        for pid, v in small:
-            h = build_exact(jax.numpy.asarray(v), v.shape[0])
-            out[pid] = _make_summary(
-                pid, v.shape[0], np.asarray(h.boundaries), np.asarray(h.sizes)
-            )
-        for n_pad, all_rows in sorted(groups.items()):
-            for at in range(0, len(all_rows), _BATCH_ROWS):
-                rows = all_rows[at : at + _BATCH_ROWS]
-                k = len(rows)
-                k_pad = next_pow2(k)
-                stack = np.stack(
-                    [r[1] for r in rows] + [rows[-1][1]] * (k_pad - k)
-                )
-                ns = np.asarray(
-                    [r[2] for r in rows] + [rows[-1][2]] * (k_pad - k),
-                    np.int32,
-                )
-                self.summarize_shapes.add((k_pad, n_pad, self.num_buckets))
-                h = build_exact_padded_batched(
-                    jax.numpy.asarray(stack), ns, self.num_buckets
-                )
-                bs, ss = np.asarray(h.boundaries), np.asarray(h.sizes)
-                for row, (pid, _, n) in enumerate(rows):
-                    out[pid] = _make_summary(pid, n, bs[row], ss[row])
+            for n_pad, all_rows in sorted(groups.items()):
+                for at in range(0, len(all_rows), _BATCH_ROWS):
+                    rows = all_rows[at : at + _BATCH_ROWS]
+                    k = len(rows)
+                    k_pad = next_pow2(k)
+                    with span("hist.summarize.pack"):
+                        stack = np.stack(
+                            [r[1] for r in rows] + [rows[-1][1]] * (k_pad - k)
+                        )
+                    ns = np.asarray(
+                        [r[2] for r in rows] + [rows[-1][2]] * (k_pad - k),
+                        np.int32,
+                    )
+                    self.summarize_shapes.add((k_pad, n_pad, self.num_buckets))
+                    with span("hist.summarize.upload", bytes=stack.nbytes):
+                        on_device = jax.numpy.asarray(stack)
+                    h = build_exact_padded_batched(
+                        on_device, ns, self.num_buckets
+                    )
+                    with span("hist.summarize.wait"):
+                        bs, ss = np.asarray(h.boundaries), np.asarray(h.sizes)
+                    for row, (pid, _, n) in enumerate(rows):
+                        out[pid] = _make_summary(pid, n, bs[row], ss[row])
         return out
 
     def _summarize(self, partition_id: int, values) -> StoredSummary:

@@ -11,9 +11,8 @@ four data planes, all served by the same summarize→merge machinery:
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
-from typing import Any, Callable
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -322,15 +321,3 @@ class TelemetryHub:
 
     def unsubscribe(self, sub) -> None:
         sub.plane.unsubscribe(sub)
-
-
-def timed(fn: Callable) -> Callable:
-    """Decorator: returns (result, wall_seconds); feeds StragglerDetector."""
-
-    def wrapper(*a, **k):
-        t0 = time.perf_counter()
-        out = fn(*a, **k)
-        jax.block_until_ready(out)
-        return out, time.perf_counter() - t0
-
-    return wrapper

@@ -101,6 +101,7 @@ in-flight pack holding node handles pins its rows (core/arena.py).
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -121,6 +122,7 @@ from repro.core.resilience import (
     TenantQuarantined,
 )
 from repro.core.scrub import scrub_registry, verify_snapshot
+from repro.core.spans import span
 from repro.core.interval_tree import (
     merge_stacks,
     pack_device_rows,
@@ -218,6 +220,9 @@ class TenantRegistry(PoolStateView):
         # cross-tenant merge dispatch observability (summarize_shapes-style)
         self.merge_dispatches = 0
         self.merge_shapes: set[tuple[int, int, int, int]] = set()
+        # sequence numbers of ingest and query calls: the ``call`` stat of
+        # each call's top span (core/spans.py)
+        self._calls = itertools.count(1)
         # ----- self-healing plane (core/resilience.py) -----
         # per-tenant circuit breakers: None → quarantine disabled (the
         # historical contract); a BreakerPolicy (assignable post-load too)
@@ -295,15 +300,16 @@ class TenantRegistry(PoolStateView):
         with self._lock:
             store = self._stores.get(name)
             if store is None:
-                store = HistogramStore(
-                    num_buckets=self.num_buckets,
-                    engine=self.engine,
-                    T_node=self.T_node,
-                    cache_size=self.cache_size,
-                    retention=self.retention,
-                    collapse=self.collapse,
-                    arena=self.arena,
-                )
+                with span("hist.tenant.create"):
+                    store = HistogramStore(
+                        num_buckets=self.num_buckets,
+                        engine=self.engine,
+                        T_node=self.T_node,
+                        cache_size=self.cache_size,
+                        retention=self.retention,
+                        collapse=self.collapse,
+                        arena=self.arena,
+                    )
                 # key the store lock by tenant name: the witness enforces
                 # the PR 5 sorted-order contract for multi-store sites
                 # (_apply_groups_batched, save) via ascending-key checks
@@ -427,10 +433,9 @@ class TenantRegistry(PoolStateView):
         WAL with one group-commit fsync; empty without a log."""
         if self._wal is None or not parts:
             return []
-        lsns = [
-            self._wal.append(tenant, pid, _validated(v))
-            for pid, v in parts.items()
-        ]
+        records = [(pid, _validated(v)) for pid, v in parts.items()]
+        with span("hist.wal.append", bytes=sum(v.nbytes for _, v in records)):
+            lsns = [self._wal.append(tenant, pid, v) for pid, v in records]
         self._wal.commit(lsns[-1])
         return lsns
 
@@ -457,40 +462,47 @@ class TenantRegistry(PoolStateView):
         is recorded against the tenant's breaker either way.
         """
         name = str(tenant)
-        self._breaker_check(name)
-        try:
-            faults.hit("tenant.apply", tenant=name, parts=1)
-            lsns = self._wal_log_sync(name, {int(partition_id): values})
-            out = self.tenant(name).ingest(partition_id, values)
-        except BaseException:
-            self._breaker_fail(name)
-            raise
-        self._breaker_ok(name)
-        self._replication_ship()
-        if self._wal is not None:
-            self._wal.mark_applied(lsns)
-        self._enforce_budget_cached([name])
-        self._notify_stale((name,))
+        with span("hist.ingest", call=next(self._calls), windows=1,
+                  values=np.size(values)):
+            self._breaker_check(name)
+            try:
+                faults.hit("tenant.apply", tenant=name, parts=1)
+                lsns = self._wal_log_sync(name, {int(partition_id): values})
+                out = self.tenant(name).ingest(partition_id, values)
+            except BaseException:
+                self._breaker_fail(name)
+                raise
+            self._breaker_ok(name)
+            self._finish_ingest(name, lsns)
         return out
 
     def ingest_many(self, tenant: str, partitions: dict[int, np.ndarray]) -> None:
         """Grouped one-dispatch bulk ingest into the named tenant (with a
         WAL: the whole batch logged under one group-commit fsync)."""
         name = str(tenant)
-        self._breaker_check(name)
-        try:
-            faults.hit("tenant.apply", tenant=name, parts=len(partitions))
-            lsns = self._wal_log_sync(name, dict(partitions))
-            self.tenant(name).ingest_many(partitions)
-        except BaseException:
-            self._breaker_fail(name)
-            raise
-        self._breaker_ok(name)
-        self._replication_ship()
-        if self._wal is not None:
-            self._wal.mark_applied(lsns)
-        self._enforce_budget_cached([name])
-        self._notify_stale((name,))
+        with span("hist.ingest", call=next(self._calls),
+                  windows=len(partitions),
+                  values=sum(np.size(v) for v in partitions.values())):
+            self._breaker_check(name)
+            try:
+                faults.hit("tenant.apply", tenant=name, parts=len(partitions))
+                lsns = self._wal_log_sync(name, dict(partitions))
+                self.tenant(name).ingest_many(partitions)
+            except BaseException:
+                self._breaker_fail(name)
+                raise
+            self._breaker_ok(name)
+            self._finish_ingest(name, lsns)
+
+    def _finish_ingest(self, name: str, lsns: list[int]) -> None:
+        """After a synchronous ingest applied: ship, mark the WAL records
+        applied, enforce the budget, notify standing queries."""
+        with span("hist.ingest.finish"):
+            self._replication_ship()
+            if self._wal is not None:
+                self._wal.mark_applied(lsns)
+            self._enforce_budget_cached([name])
+            self._notify_stale((name,))
 
     def ingest_async(self, tenant: str, partition_id: int, values) -> None:
         """Enqueue one partition for the shared background worker pool.
@@ -819,10 +831,75 @@ class TenantRegistry(PoolStateView):
         results: list[tuple[Histogram | None, float] | None] = [None] * len(
             queries
         )
-        # mkey (store id + cache key) → (miss row, result slots)
+        with span("hist.query", call=next(self._calls),
+                  queries=len(queries)) as top:
+            with span("hist.query.select"):
+                miss_map, miss_sels, miss_meta, hits = self._select_misses(
+                    queries, beta, strict, degraded_ok, results
+                )
+            top.set_metadata(misses=len(miss_sels), hits=hits)
+            if not miss_sels:
+                return results
+            try:
+                if deadline is not None and self._clock() >= deadline:
+                    raise TimeoutError(
+                        "query deadline passed before the merge dispatch"
+                    )
+                faults.hit("tenant.merge", misses=len(miss_sels))
+                # ONE cross-tenant merge dispatch for the whole batch.
+                # Packing outside the store locks is safe: arena rows are
+                # write-once and the node handles held in miss_sels pin
+                # them against concurrent eviction + reuse (core/arena.py
+                # slot lifecycle).
+                with span("hist.query.pack"):
+                    bounds, sizes = self._pack_misses(miss_sels)
+                with self._lock:  # counters read by concurrent servers
+                    self.merge_dispatches += 1
+                    self.merge_shapes.add(tuple(bounds.shape) + (int(beta),))
+                with span("hist.query.merge"):
+                    bo, so = merge_stacks(bounds, sizes, int(beta))
+                # one device→host transfer; per-row unpacking is free views
+                with span("hist.query.wait"):
+                    bo, so = np.asarray(bo), np.asarray(so)
+            except BaseException:
+                if not degraded_ok:
+                    raise
+                # the dispatch failed (or the deadline passed): every miss
+                # gets its last known-good answer, honestly widened
+                for row, slots in miss_map.values():
+                    _store, _key, gkey, members = miss_meta[row]
+                    ans = self._degraded_answer(gkey, members)
+                    for qi in slots:
+                        results[qi] = ans
+                return results
+            with span("hist.query.assemble"):
+                for row, slots in miss_map.values():
+                    store, key, gkey, members = miss_meta[row]
+                    out = (
+                        Histogram(bo[row], so[row]),
+                        selection_eps(miss_sels[row]),
+                    )
+                    with store._lock:
+                        store._tree._cache_put(key, out)
+                    if members is not None:
+                        self._remember_good(gkey, out, members, key[3])
+                    for qi in slots:
+                        results[qi] = out
+        return results
+
+    def _select_misses(self, queries, beta, strict, degraded_ok, results):
+        """:meth:`query_many`'s per-query pass: answer cache hits and
+        placeholders into ``results``, and collect the deduplicated misses.
+
+        Returns ``(miss_map, miss_sels, miss_meta, hits)``: ``miss_map``
+        maps a miss key (store id + cache key) to its row and result slots,
+        ``miss_sels[row]`` is the row's canonical nodes, ``miss_meta[row]``
+        its ``(store, cache key, gkey, members)``.
+        """
         miss_map: dict[tuple, tuple[int, list[int]]] = {}
         miss_sels: list[list] = []
         miss_meta: list[tuple[HistogramStore, tuple, tuple, dict | None]] = []
+        hits = 0
         for qi, (name, lo, hi) in enumerate(queries):
             if not strict and name not in self:
                 results[qi] = (None, float("inf"))
@@ -857,6 +934,7 @@ class TenantRegistry(PoolStateView):
                     hit = tree._cache_get(key)
                     if hit is not None:
                         results[qi] = hit
+                        hits += 1
                         continue
                     tree.cache_misses += 1
                     sel = [tree.nodes[k] for k in keys]
@@ -874,66 +952,24 @@ class TenantRegistry(PoolStateView):
                 if not degraded_ok:
                     raise
                 results[qi] = self._degraded_answer(gkey)
-        if miss_sels:
-            try:
-                if deadline is not None and self._clock() >= deadline:
-                    raise TimeoutError(
-                        "query deadline passed before the merge dispatch"
-                    )
-                faults.hit("tenant.merge", misses=len(miss_sels))
-                # ONE cross-tenant merge dispatch for the whole batch.
-                # Packing outside the store locks is safe: arena rows are
-                # write-once and the node handles held in miss_sels pin
-                # them against concurrent eviction + reuse (core/arena.py
-                # slot lifecycle).
-                packed = None
-                if self.arena is not None:
-                    # shared arena: assemble the whole merge stack with a
-                    # single device gather — zero host-side row copies
-                    packed = pack_device_rows(miss_sels)
-                    if packed is None:
-                        with self._lock:
-                            self.pack_fallbacks += 1
-                if packed is None:
-                    # per-tenant arenas (or a mixed-plane selection, e.g.
-                    # geometric T_node): host pack, one stacked copy per
-                    # plane, padded to the plane width so the block is
-                    # bit-identical to the gather path's
-                    T_pad = max(nd.width for sel in miss_sels for nd in sel)
-                    packed = pack_node_rows(
-                        miss_sels, T_pad=T_pad, pad_row_copy=True
-                    )
-                bounds, sizes = packed
-                with self._lock:  # counters read by concurrent servers
-                    self.merge_dispatches += 1
-                    self.merge_shapes.add(tuple(bounds.shape) + (int(beta),))
-                bo, so = merge_stacks(bounds, sizes, int(beta))
-                # one device→host transfer; per-row unpacking is free views
-                bo, so = np.asarray(bo), np.asarray(so)
-            except BaseException:
-                if not degraded_ok:
-                    raise
-                # the dispatch failed (or the deadline passed): every miss
-                # gets its last known-good answer, honestly widened
-                for row, slots in miss_map.values():
-                    _store, _key, gkey, members = miss_meta[row]
-                    ans = self._degraded_answer(gkey, members)
-                    for qi in slots:
-                        results[qi] = ans
-                return results
-            for row, slots in miss_map.values():
-                store, key, gkey, members = miss_meta[row]
-                out = (
-                    Histogram(bo[row], so[row]),
-                    selection_eps(miss_sels[row]),
-                )
-                with store._lock:
-                    store._tree._cache_put(key, out)
-                if members is not None:
-                    self._remember_good(gkey, out, members, key[3])
-                for qi in slots:
-                    results[qi] = out
-        return results
+        return miss_map, miss_sels, miss_meta, hits
+
+    def _pack_misses(self, miss_sels: list[list]):
+        """Stack the misses' node sets into one ``(Q, k_pad, T_pad)``
+        merge block: one device gather over the shared arena, else (per-
+        tenant arenas, or a mixed-plane selection such as geometric
+        ``T_node``) the host pack, padded to the plane width so the block
+        is bit-identical to the gather's."""
+        if self.arena is not None:
+            # shared arena: assemble the whole merge stack with a single
+            # device gather — zero host-side row copies
+            packed = pack_device_rows(miss_sels)
+            if packed is not None:
+                return packed
+            with self._lock:
+                self.pack_fallbacks += 1
+        T_pad = max(nd.width for sel in miss_sels for nd in sel)
+        return pack_node_rows(miss_sels, T_pad=T_pad, pad_row_copy=True)
 
     def _remember_good(
         self, gkey: tuple, out: tuple, members: dict, version: int
@@ -1211,15 +1247,23 @@ class TenantRegistry(PoolStateView):
 
     # ------------------------------------------------------------- utility
     def cache_stats(self) -> dict[str, int]:
-        """Aggregated per-tenant cache counters + registry dispatch count."""
+        """Aggregated per-tenant cache counters, the registry's dispatch
+        count, and the arenas' whole-plane device uploads and bytes."""
         with self._lock:
             stores = list(self._stores.values())
         hits = sum(s._tree.cache_hits for s in stores)
         misses = sum(s._tree.cache_misses for s in stores)
+        arenas = (
+            [self.arena]
+            if self.arena is not None
+            else [s._tree.arena for s in stores]
+        )
         return {
             "hits": hits,
             "misses": misses,
             "merge_dispatches": self.merge_dispatches,
             "merge_shapes": len(self.merge_shapes),
             "host_row_copies": self.host_row_copies,
+            "device_uploads": sum(a.device_uploads for a in arenas),
+            "device_upload_bytes": sum(a.device_upload_bytes for a in arenas),
         }

@@ -133,6 +133,7 @@ import numpy as np
 
 from repro.analysis.witness import OrderedLock, OrderedRLock
 from repro.core import faults
+from repro.core.spans import span
 from repro.core.resilience import (
     IngestBackpressure,
     PrimaryFenced,
@@ -294,6 +295,10 @@ class WriteAheadLog:
         self.append_rollbacks = 0
         self.fsync_seconds = 0.0
         self.last_fsync_seconds = 0.0
+        # segment rotations and the fsync of each outgoing segment, which
+        # runs inside append(): apart from the commit fsyncs above
+        self.rolls = 0
+        self.roll_fsync_seconds = 0.0
         self.bytes_written = 0
         self.torn_records_dropped = 0
         # epoch fencing (module docstring): the writer's epoch is stamped
@@ -400,7 +405,8 @@ class WriteAheadLog:
                 or self._fd_broken
                 or self._fd.tell() >= self.segment_bytes
             ):
-                self._roll(lsn)
+                with span("hist.wal.roll"):
+                    self._roll(lsn)
             buf = _WAL_PREFIX.pack(_WAL_MAGIC, lsn, crc, len(header))
             data = buf + header + payload
             pos = self._fd.tell()
@@ -460,12 +466,13 @@ class WriteAheadLog:
             # close() interrupts the wait, and the remaining attempts
             # still run — a persistent failure propagates to the
             # submitter, which surfaces it as backpressure
-            retry_call(
-                _sync,
-                self.retry,
-                wait=self._interrupt.wait,
-                on_retry=_count,
-            )
+            with span("hist.wal.fsync"):
+                retry_call(
+                    _sync,
+                    self.retry,
+                    wait=self._interrupt.wait,
+                    on_retry=_count,
+                )
             dt = time.perf_counter() - t0
             self.fsyncs += 1
             self.fsync_seconds += dt
@@ -483,10 +490,13 @@ class WriteAheadLog:
     def _roll(self, first_lsn: int) -> None:
         """Rotate to a fresh segment (callers hold ``_lock``)."""
         if self._fd is not None:
+            self.rolls += 1
             try:
                 self._fd.flush()
                 if self.fsync_enabled:
+                    t0 = time.perf_counter()
                     os.fsync(self._fd.fileno())
+                    self.roll_fsync_seconds += time.perf_counter() - t0
                 synced = True
             except OSError:
                 # a broken outgoing fd (failed append rollback): records
@@ -836,6 +846,8 @@ class WriteAheadLog:
                 "fsync_retries": self.fsync_retries,
                 "fsync_seconds_total": self.fsync_seconds,
                 "last_fsync_seconds": self.last_fsync_seconds,
+                "rolls": self.rolls,
+                "roll_fsync_seconds_total": self.roll_fsync_seconds,
                 "bytes_written": self.bytes_written,
                 "segments": len(self._segments),
                 "depth": self._written_lsn - self._stable,
