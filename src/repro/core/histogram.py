@@ -278,9 +278,12 @@ def pre_histogram(histograms: Histogram) -> tuple[jax.Array, jax.Array]:
         [s, jnp.zeros((k, 1), dtype=s.dtype)], axis=-1
     ).reshape(-1)
     flat = b.reshape(-1)
-    order = jnp.argsort(flat, stable=True)
-    pos = flat[order]
-    cum = jnp.cumsum(mass[order])
+    # One stable sort keyed on the boundaries alone carries the masses
+    # along as its payload, so no permutation gather follows it.  Stability
+    # keeps the masses of tied boundaries in input order, which fixes ``A``
+    # at every tie and with it every cut.
+    pos, mass = jax.lax.sort((flat, mass), num_keys=1, is_stable=True)
+    cum = jnp.cumsum(mass)
     return pos, cum[:-1]
 
 
@@ -289,7 +292,8 @@ def merge(histograms: Histogram, beta: int) -> Histogram:
     """Merge ``k`` stacked ``T``-bucket summaries into a β-bucket histogram.
 
     Vectorized rank-select equivalent of paper Algorithm 1 (see module
-    docstring).  Fully jit-able: one sort + cumsum + batched searchsorted.
+    docstring).  Fully jit-able: one sort of the boundaries that carries
+    the masses along + cumsum + batched searchsorted.
     """
     # named scopes tag the device ops of each phase in a profiler trace
     with jax.named_scope("merge.presort"):

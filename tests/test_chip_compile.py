@@ -12,7 +12,9 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and test
 collection happens in every worker.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -81,14 +83,54 @@ def test_summarizer_compiles_for_v5e(one_chip):
     )
 
 
+# one panel's pre-histogram holds k_pad·(T+1) boundaries, a batch Q of them
+PRE_HISTOGRAM = PANELS * K_PAD * (T + 1)
+
+
+def _gather_sizes(hlo_text: str) -> list[int]:
+    """Element count of every gather's output in an HLO module's text, in
+    either form: StableHLO (``"stablehlo.gather"(...) ... -> tensor<AxBxf32>``)
+    or a compiled module (``%gather.3 = f32[A,B]{1,0} gather(...)``, fused
+    computations included)."""
+    sizes = []
+    for line in hlo_text.splitlines():
+        if '"stablehlo.gather"' in line:
+            m = re.search(r"->\s*tensor<([\dx]+)x[a-z]\w*>", line)
+        elif re.search(r"\bgather\(", line):
+            m = re.search(r"=\s*[a-z]\w*\[([\d,]*)\]", line)
+        else:
+            continue
+        assert m, line
+        dims = [int(d) for d in re.split(r"[x,]", m.group(1)) if d]
+        sizes.append(math.prod(dims))
+    return sizes
+
+
+def _no_pre_histogram_gather(hlo_text: str) -> None:
+    # the pre-histogram's boundaries and masses come out of one sort; a
+    # gather of its full length would be a permutation by an argsort
+    sizes = _gather_sizes(hlo_text)
+    assert PRE_HISTOGRAM not in sizes, sizes
+
+
 def test_query_merge_compiles_for_v5e(one_chip):
-    _fits(
-        merge_stacks.lower(
-            _spec(one_chip, (PANELS, K_PAD, T + 1)),
-            _spec(one_chip, (PANELS, K_PAD, T)),
-            beta=BETA,
-        ).compile()
+    compiled = merge_stacks.lower(
+        _spec(one_chip, (PANELS, K_PAD, T + 1)),
+        _spec(one_chip, (PANELS, K_PAD, T)),
+        beta=BETA,
+    ).compile()
+    _fits(compiled)
+    _no_pre_histogram_gather(compiled.as_text())
+
+
+def test_query_merge_lowers_without_pre_histogram_gather():
+    # the same check on the portable lowering, where no v5e can be described
+    lowered = merge_stacks.lower(
+        jax.ShapeDtypeStruct((PANELS, K_PAD, T + 1), jnp.float32),
+        jax.ShapeDtypeStruct((PANELS, K_PAD, T), jnp.float32),
+        beta=BETA,
     )
+    _no_pre_histogram_gather(lowered.as_text())
 
 
 def test_arena_gather_compiles_for_v5e(one_chip):

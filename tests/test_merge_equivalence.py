@@ -1,6 +1,10 @@
 """The vectorized rank-select merge is bit-identical to paper Algorithm 1."""
+from unittest import mock
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (
@@ -9,6 +13,8 @@ from repro.core import (
     merge,
     merge_histograms_sequential,
 )
+from repro.core import histogram
+from repro.core.interval_tree import merge_stacks
 from repro.kernels import merge_pallas
 
 settings.register_profile("ci", deadline=None, max_examples=60)
@@ -63,3 +69,109 @@ def test_pallas_kernel_equals_sequential(args):
     hq = merge_histograms_sequential(hs, beta)
     np.testing.assert_allclose(np.asarray(bo), np.asarray(hq.boundaries))
     np.testing.assert_allclose(np.asarray(so), np.asarray(hq.sizes), atol=1e-2)
+
+
+# ---------------------------------------------------------------------------
+# Bit identity of the one-sort pre-histogram against argsort-then-gather
+# ---------------------------------------------------------------------------
+
+_T = 12
+_K_PAD = 8
+
+
+def _pre_histogram_argsort(histograms):
+    """The pre-histogram by argsort and two permutation gathers — the
+    form the multi-operand sort replaced, kept here as the oracle."""
+    b, s = histograms.boundaries, histograms.sizes
+    k = b.shape[0]
+    mass = jnp.concatenate([s, jnp.zeros((k, 1), s.dtype)], axis=-1).reshape(-1)
+    flat = b.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    cum = jnp.cumsum(mass[order])
+    return flat[order], cum[:-1]
+
+
+def _summary_row(rng, features, T_row):
+    n = int(rng.integers(T_row, 300))
+    if "ties" in features:
+        v = rng.integers(-3, 5, size=n).astype(np.float32)
+    else:
+        v = (rng.normal(size=n) * 5).astype(np.float32)
+    if "signed_zero" in features:
+        zeros = rng.random(n) < 0.3
+        v[zeros] = np.where(rng.random(zeros.sum()) < 0.5, -0.0, 0.0)
+    sv = np.sort(v)
+    cuts = histogram._cut_indices(n, T_row)
+    b = sv[np.minimum(cuts, n - 1)]
+    s = np.diff(cuts).astype(np.float32)
+    if "signed_zero" in features:
+        # numpy orders ±0 arbitrarily; flip the sign of boundary zeros at
+        # random so -0.0 and 0.0 sit next to each other in and across rows
+        z = b == 0
+        b[z] = np.where(rng.random(z.sum()) < 0.5, -0.0, 0.0)
+    if T_row < _T:
+        # a narrower source padded to T with zero-size tail buckets
+        b = np.concatenate([b, np.repeat(b[-1:], _T - T_row)])
+        s = np.concatenate([s, np.zeros(_T - T_row, np.float32)])
+    return b, s
+
+
+def _stacked_batch(features, Q, seed):
+    """``(Q, k_pad, T+1)``/``(Q, k_pad, T)`` stacks shaped like the served
+    path's merge inputs, with the requested hard cases."""
+    rng = np.random.default_rng(seed)
+    B = np.empty((Q, _K_PAD, _T + 1), np.float32)
+    S = np.empty((Q, _K_PAD, _T), np.float32)
+    for q in range(Q):
+        k = int(rng.integers(1, _K_PAD + 1)) if "pad_rows" in features else _K_PAD
+        for j in range(k):
+            T_row = int(rng.integers(2, _T + 1)) if "tail_pad" in features else _T
+            B[q, j], S[q, j] = _summary_row(rng, features, T_row)
+        # pad rows as _gather_rows makes them: a real row, mass masked to 0
+        B[q, k:] = B[q, 0]
+        S[q, k:] = S[q, 0] * np.float32(0.0)
+    return B, S
+
+
+def _bits(x):
+    # compare raw float32 bits: array_equal alone calls -0.0 equal to 0.0
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("Q", [1, 5])
+@pytest.mark.parametrize(
+    "features",
+    [
+        ("ties",),
+        ("pad_rows",),
+        ("tail_pad",),
+        ("signed_zero",),
+        ("ties", "pad_rows", "tail_pad", "signed_zero"),
+    ],
+    ids="+".join,
+)
+def test_one_sort_pre_histogram_is_bit_identical(features, Q, seed):
+    B, S = _stacked_batch(features, Q, seed)
+    rng = np.random.default_rng(seed + 1000)
+    beta = int(rng.integers(1, _T + 1))
+    b, s = jnp.asarray(B), jnp.asarray(S)
+
+    pre = jax.vmap(lambda b, s: histogram.pre_histogram(Histogram(b, s)))
+    got_pos, got_A = jax.jit(pre)(b, s)
+    oracle = jax.vmap(lambda b, s: _pre_histogram_argsort(Histogram(b, s)))
+    want_pos, want_A = jax.jit(oracle)(b, s)
+    assert np.array_equal(_bits(got_pos), _bits(want_pos))
+    assert np.array_equal(_bits(got_A), _bits(want_A))
+
+    got = merge_stacks(b, s, beta=beta)
+    with mock.patch.object(histogram, "pre_histogram", _pre_histogram_argsort):
+        want = jax.jit(
+            jax.vmap(lambda b, s: merge.__wrapped__(Histogram(b, s), beta))
+        )(b, s)
+    assert np.array_equal(_bits(got[0]), _bits(want.boundaries))
+    assert np.array_equal(_bits(got[1]), _bits(want.sizes))
+    for q in range(Q):  # the unbatched merge agrees with its batched form
+        one = merge(Histogram(b[q], s[q]), beta)
+        assert np.array_equal(_bits(one.boundaries), _bits(got[0][q]))
+        assert np.array_equal(_bits(one.sizes), _bits(got[1][q]))
