@@ -5,7 +5,8 @@ computed one precision lower than the configuration states.
     python3 bench/control.py --workload <name> --seeds <n> [<n> ...]
 
 For each seed it makes the cell's data at the cell's own size, draws
-``check_answers`` panels as the cell's clients draw them, answers each with
+``check_answers`` panels as the cell draws those it checks after the window
+(``Cell.sample``, inside whole periods of every metric), answers each with
 the exact equi-depth histogram computed in bfloat16 (the configuration
 states float32) and compares it by ``reference.measure``.  The same
 histogram in float32 is compared too: it has to pass, so that the control
@@ -30,29 +31,22 @@ import generator  # noqa: E402
 import harness  # noqa: E402
 
 
-def panels(cell: generator.Cell, n: int) -> list[generator.Panel]:
-    """``n`` panels as the cell's first client would send them, or as the
-    cell checks its loaded tenants, inside the windows that the data holds."""
-    c = cell.clients[0]
-    if isinstance(c, generator.QueryClient):
-        return [generator.Panel(cell.data.names[m], m, lo, hi)
-                for m, lo, hi in (c.panel() for _ in range(n))]
-    last = int(cell.config["windows"]) - 1
-    for m in range(cell.data.metrics):
-        for period in range(2):
-            p = generator.Panel(f"{cell.data.names[m]}.{period}", m, 0, last,
-                                period * (last + 1))
-            c.loaded[p.tenant] = p
-    return cell.check_panels()[1][:n]
+def whole_periods(cell: generator.Cell, periods: int = 2) -> list[generator.Panel]:
+    """Every metric's windows of its first ``periods`` periods, a panel
+    each, as an ingest client reports the tenants it loaded (a query
+    client's panels lie inside the first)."""
+    W = int(cell.config["windows"])
+    return [generator.Panel(f"{name}.{p}", m, 0, W - 1, p * W)
+            for m, name in enumerate(cell.data.names) for p in range(periods)]
 
 
 def readings(root: str, workload: str, seed: int) -> dict:
-    _w, config, traffic, _e2e, _layer = harness.cell_parts(root, workload)
-    reference = harness._module(os.path.join(root, config["reference"]))
-    cell = generator.Cell(config, traffic, seed)
+    _w, config, traffic, parts, _e2e, _layer = harness.cell_parts(root, workload)
+    reference = generator.load_module(os.path.join(root, config["reference"]))
+    cell = generator.Cell(config, traffic, seed, parts)
     beta, T = int(config["beta"]), int(config["T"])
     per_window = cell.data.per_window
-    asked = panels(cell, int(traffic.get("check_answers", 32)))
+    asked = cell.sample(whole_periods(cell))
     out = {}
     for label, dtype in (("control_bfloat16", ml_dtypes.bfloat16), ("reference_float32", np.float32)):
         got = []

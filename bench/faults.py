@@ -89,19 +89,6 @@ def coarse_summaries(mp):
     mp.setattr(HistogramService, "__init__", init)
 
 
-def coarse_summaries(mp):
-    """The service built with half the configured T: cheaper summaries whose
-    answers honestly report a wider ε."""
-    from repro.serve import HistogramService
-
-    orig = HistogramService.__init__
-
-    def init(self, *args, num_buckets, **kw):
-        orig(self, *args, num_buckets=num_buckets // 2, **kw)
-
-    mp.setattr(HistogramService, "__init__", init)
-
-
 FAULTS = {f.__name__: f for f in (state_unchanged, half_of_each_ingest, half_of_each_panel,
                                   altered_answer, coarse_summaries)}
 BEFORE_SETUP = {"coarse_summaries"}

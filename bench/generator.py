@@ -1,54 +1,58 @@
 """The one traffic generator: data, preload, warm-up and client loops of a cell.
 
-A traffic mix is a JSON file under ``bench/traffic/`` that this module reads;
-a configuration is a JSON file under ``bench/configs/``.  Nothing here knows
-a mix or a configuration by name, so a new cell brings data files only.
+A traffic mix is a JSON file under ``bench/traffic/``; a configuration is a
+JSON file under ``bench/configs/``.  What varies between deployments is
+found by name as a file of its own, so a new cell brings new files only:
 
-Traffic keys (all optional except ``clients``):
+- the value model ``bench/values/<values.distribution>.py`` defines
+  ``build(config, traffic, seed)``, which returns the cell's data: an
+  object with ``metrics``, ``per_window``, ``names``, ``window(m, w)`` and
+  ``pooled(m, lo, hi)`` (float32).  :class:`Windows` is the common layout;
+  a model gives it the function that draws a metric's windows.
+- the client kind ``bench/clients/<kind>.py`` of each entry of the mix's
+  ``clients`` defines one class, ``Client``, with a ``role`` (``"query"``
+  or ``"ingest"``: the end-to-end readers read by role), built as
+  ``Client(spec, config, data, seed, index)``, and with
+
+  - ``warm(open_service, load)``: compile every shape the window can
+    produce, on throwaway services (``open_service()`` opens one;
+    ``load(svc)`` ingests the mix's preload into it);
+  - ``run(svc, t_end, annotate, stats)``: the client's loop until
+    ``t_end``, each request recorded in ``stats`` (:class:`ClientStats`)
+    with the start the client gives it: a closed loop times from the
+    call, an open loop from when the request was due;
+  - ``check_panels()``: panels whose mass (all the values the client had
+    acked) and answers are checked once the window has closed;
+  - optionally ``lead_in(svc)``: the client's first requests, made on the
+    served service at the end of set-up, after the warm-up's services
+    have closed, so that the window opens on the client's steady state.
+
+:func:`find_parts` finds both; a name with no file is an error before any
+work.  Traffic keys read here (all optional except ``clients``):
 
 - ``preload``: windows per metric ingested in set-up, or ``"all"`` for the
   configuration's ``windows`` (default 0).
-- ``value_pool``: when set, every window's values are one of this many
-  seeded pool windows, cycled over window ids from a per-metric offset;
-  otherwise each metric draws its own windows from its own distribution.
+- ``value_pool``: read by :class:`Windows`.
 - ``check_answers``: answers compared in full with the reference (default 32).
-- ``clients``: one entry per client thread, by ``kind``:
-
-  - ``query``: closed loop of ``svc.query_many`` batches of ``batch``
-    panels over the preloaded windows.  ``span`` is ``{"uniform": [lo,
-    hi]}`` or ``{"choice": [...]}`` (a number or ``"windows"``).  The
-    client sends every panel those spans allow, over every metric, in an
-    order drawn from the seed, and starts the order again when it is
-    through: no panel recurs within a cycle.
-  - ``ingest_many``: closed loop of ``registry.ingest_many`` calls of
-    ``windows_per_call`` consecutive windows (a number or ``"windows"``),
-    metrics round robin.  A tenant holds the configuration's ``windows``;
-    once a metric's tenant is full the metric's next call starts a new
-    tenant ``<metric>.<n>``, the next period of the same deployment.
-
-Every request is timed on the host clock from when it was sent until its
-answer or ack is back.
+- ``clients``: one entry per client thread; the rest of an entry is its
+  kind's to read.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
+import os
+import re
+import sys
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
 from reference import cover
 
-# Entries of a tenant's answer cache (the service's default LRU size): a
-# cycling client whose cycle is longer than a batch and this together never
-# hits it.
-ANSWER_CACHE = 128
-
-# Whole tenants an ingest client loads on a throwaway service in set-up:
-# the second is loaded with the first's programs, as every call of the
-# window is.
-WARM_TENANTS = 2
+ROLES = ("query", "ingest")
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 def seed_rng(seed: int, *stream: int) -> np.random.Generator:
@@ -69,36 +73,54 @@ def metric_names(n: int) -> list[str]:
     return [f"metric_{i:03d}" for i in range(n)]
 
 
-class Data:
+def resolve(x, config) -> int:
+    """A count from a mix: a number, or ``"windows"`` for the configuration's."""
+    return int(config["windows"]) if x == "windows" else int(x)
+
+
+def load_module(path: str):
+    """The Python file at ``path`` as a module of its own."""
+    stem = os.path.basename(path)[:-3].replace(".", "_").replace("-", "_")
+    name = f"bench_{os.path.basename(os.path.dirname(path))}_{stem}"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # as an import would: dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_part(bench: str, folder: str, name: str):
+    """``bench/<folder>/<name>.py``: a value model or a client kind."""
+    path = os.path.join(bench, folder, f"{name}.py")
+    if not isinstance(name, str) or not NAME.match(name) or not os.path.isfile(path):
+        raise FileNotFoundError(f"no file bench/{folder}/{name}.py for {name!r}")
+    return load_module(path)
+
+
+class Windows:
     """Raw float32 values of every (metric, window) a cell can touch.
 
-    Values are Gumbel-skewed (``loc - scale·ln E`` with ``E ~ Exp(1)``),
-    ``loc`` and ``scale`` drawn per metric (or per pool window) from the
-    configuration's ranges.  The same seed gives the same values.
+    ``draw(rng, n)`` gives ``n`` windows of one metric, shape ``(n,
+    per_window)``, from the seed's stream 1.  Each metric draws the
+    configuration's ``windows``, and window ``w`` reads ``w`` modulo that;
+    where the mix sets ``value_pool``, that many single windows are drawn
+    instead and every window is one of them, cycled over window ids from
+    a per-metric offset.  The same seed gives the same values.
     """
 
-    def __init__(self, config: dict, traffic: dict, seed: int):
-        dist = config["values"]
+    def __init__(self, config: dict, traffic: dict, seed: int, draw):
         self.metrics = int(config["metrics"])
         self.per_window = int(config["values_per_window"])
         self.names = metric_names(self.metrics)
         rng = seed_rng(seed, 1)
         pool = traffic.get("value_pool")
-
-        def draw(n_windows: int) -> np.ndarray:
-            loc = rng.uniform(*dist["loc"])
-            scale = rng.uniform(*dist["scale"])
-            e = rng.standard_exponential((n_windows, self.per_window), np.float32)
-            np.maximum(e, np.finfo(np.float32).tiny, out=e)  # ln 0 would be infinite
-            return (loc - scale * np.log(e)).astype(np.float32)
-
         if pool:
-            self.pool = np.concatenate([draw(1) for _ in range(int(pool))])
+            self.pool = np.concatenate([draw(rng, 1) for _ in range(int(pool))])
             self.offset = rng.integers(0, int(pool), self.metrics)
             self.arrays = None
         else:
             self.pool = None
-            self.arrays = [draw(int(config["windows"])) for _ in range(self.metrics)]
+            self.arrays = [draw(rng, int(config["windows"])) for _ in range(self.metrics)]
 
     def window(self, m: int, w: int) -> np.ndarray:
         if self.arrays is not None:
@@ -125,13 +147,25 @@ class Panel:
     hi: int
     shift: int = 0
 
-    def values(self, data: Data) -> np.ndarray:
+    def values(self, data) -> np.ndarray:
         return data.pooled(self.m, self.shift + self.lo, self.shift + self.hi)
+
+
+def sample_panels(full: list[Panel], n: int, rng: np.random.Generator) -> list[Panel]:
+    """``n`` panels drawn inside the panels of ``full``."""
+    sample = []
+    while len(sample) < n and full:
+        p = full[int(rng.integers(len(full)))]
+        lo = int(rng.integers(0, p.hi + 1))
+        hi = int(rng.integers(lo, p.hi + 1))
+        sample.append(dataclasses.replace(p, lo=lo, hi=hi))
+    return sample
 
 
 @dataclasses.dataclass
 class Request:
-    """One timed request, from when it was sent until its answer or ack."""
+    """One timed request, from its start (as its client gives it) until its
+    answer or ack."""
 
     start: float
     end: float
@@ -145,6 +179,7 @@ class Request:
 @dataclasses.dataclass
 class ClientStats:
     kind: str
+    role: str
     requests: list[Request] = dataclasses.field(default_factory=list)
     attempted: int = 0  # panels or windows
     failed: int = 0
@@ -152,169 +187,35 @@ class ClientStats:
     cover_nodes: int = 0  # query: their canonical nodes
     kept: list = dataclasses.field(default_factory=list)  # answers to check
 
-    def work(self) -> int:
-        return sum(r.work for r in self.requests)
 
-
-def _resolve(x, config):
-    return int(config["windows"]) if x == "windows" else int(x)
-
-
-class QueryClient:
-    def __init__(self, spec, config, data, seed, index):
-        self.data = data
-        self.windows = int(config["windows"])
-        self.batch = int(spec["batch"])
-        self.beta = int(config["beta"])
-        self.rng = seed_rng(seed, 2, index)
-        self.keep_rng = seed_rng(seed, 3, index)
-        span = spec["span"]
-        if "uniform" in span:
-            a, b = (_resolve(x, config) for x in span["uniform"])
-            self.spans = list(range(a, b + 1))
-        else:
-            self.spans = [_resolve(x, config) for x in span["choice"]]
-        every = [(m, lo, hi) for m in range(data.metrics) for lo, hi in self.ranges()]
-        self.order = [every[i] for i in self.rng.permutation(len(every))]
-        self.pos = 0
-
-    def ranges(self) -> list[tuple[int, int]]:
-        """Every (lo, hi) this client can send."""
-        return [(hi - s + 1, hi) for s in sorted(set(self.spans))
-                for hi in range(s - 1, self.windows)]
-
-    def panel(self) -> tuple[int, int, int]:
-        key = self.order[self.pos % len(self.order)]
-        self.pos += 1
-        return key
-
-    def shapes(self) -> tuple[list[int], list[int]]:
-        """Miss counts and padded cover sizes the window's batches can have:
-        every panel of a batch misses the answer cache while a cycle holds
-        more panels than a batch and the cache together; otherwise hits
-        and repeats lower the count."""
-        L, B = len(self.order), self.batch
-        qs = [B] if B + ANSWER_CACHE <= L else list(range(1, min(B, L) + 1))
-        ks = sorted({next_pow2(cover_size(lo, hi)) for lo, hi in self.ranges()})
-        return qs, ks
-
-    def warm_batches(self) -> list[list[tuple[str, int, int]]]:
-        """One batch of distinct panels for every (misses, padded cover)
-        pair the window can produce: one panel of that cover, the rest of
-        covers no larger."""
-        qs, ks = self.shapes()
-        by_k: dict[int, list[tuple[int, int]]] = {}
-        for lo, hi in self.ranges():
-            by_k.setdefault(next_pow2(cover_size(lo, hi)), []).append((lo, hi))
-        names = self.data.names
-        rng = seed_rng(0, 6)
-        batches = []
-        for K in ks:
-            small = [(m, lo, hi) for k, rs in by_k.items() if k <= K
-                     for lo, hi in rs for m in range(self.data.metrics)]
-            first = (0, *by_k[K][0])
-            rest = [small[i] for i in rng.permutation(len(small)) if small[i] != first]
-            for Q in qs:
-                if Q - 1 <= len(rest):  # else fewer distinct panels exist: unreachable
-                    picked = [first] + rest[: Q - 1]
-                    batches.append([(names[m], lo, hi) for m, lo, hi in picked])
-        return batches
-
-    def run(self, svc, t_end: float, annotate, stats: ClientStats) -> None:
-        names = self.data.names
-        while time.perf_counter() < t_end:
-            panels = [self.panel() for _ in range(self.batch)]
-            distinct = set(panels)
-            stats.distinct += len(distinct)
-            stats.cover_nodes += sum(cover_size(lo, hi) for _, lo, hi in distinct)
-            batch = [(names[m], lo, hi) for m, lo, hi in panels]
-            t0 = time.perf_counter()
-            with annotate("bench.query_many"):
-                answers = svc.query_many(batch, beta=self.beta)
-            t1 = time.perf_counter()
-            bad = sum(1 for a in answers if a[0] is None or getattr(a, "degraded", False))
-            stats.requests.append(Request(t0, t1, len(panels) - bad))
-            stats.attempted += len(panels)
-            stats.failed += bad
-            k = int(self.keep_rng.integers(len(panels)))
-            h, eps = answers[k]
-            if h is not None and not getattr(answers[k], "degraded", False):
-                m, lo, hi = panels[k]
-                stats.kept.append(
-                    (Panel(names[m], m, lo, hi), np.array(h.boundaries), np.array(h.sizes), float(eps))
-                )
-
-
-class IngestManyClient:
-    def __init__(self, spec, config, data, seed, index):
-        self.windows = int(config["windows"])
-        self.per_call = _resolve(spec["windows_per_call"], config)
-        self.data = data
-        self.period = [0] * data.metrics  # the tenant each metric is filling
-        self.acked = [-1] * data.metrics  # its newest acked window
-        self.loaded: dict[str, Panel] = {}  # tenant -> all its acked windows
-
-    def _call(self, svc, m: int, period: int, first: int, last: int) -> str:
-        """Ingest windows ``first..last`` of metric ``m``'s tenant of
-        ``period``; returns the tenant."""
-        shift = period * self.windows
-        tenant = f"{self.data.names[m]}.{period}"
-        parts = {w: self.data.window(m, shift + w) for w in range(first, last + 1)}
-        svc.registry.ingest_many(tenant, parts)
-        return tenant
-
-    def _last(self, first: int) -> int:
-        return min(first + self.per_call, self.windows) - 1
-
-    def warm(self, svc) -> None:
-        for period in range(WARM_TENANTS):
-            for first in range(0, self.windows, self.per_call):
-                self._call(svc, 0, period, first, self._last(first))
-
-    def run(self, svc, t_end: float, annotate, stats: ClientStats) -> None:
-        m = 0
-        per_window = self.data.per_window
-        while time.perf_counter() < t_end:
-            period, first = self.period[m], self.acked[m] + 1
-            last = self._last(first)
-            n = last - first + 1
-            stats.attempted += n
-            t0 = time.perf_counter()
-            try:
-                with annotate("bench.ingest_many"):
-                    tenant = self._call(svc, m, period, first, last)
-            except Exception:  # a failed call acks nothing; counted, not raised
-                stats.requests.append(Request(t0, time.perf_counter(), 0))
-                stats.failed += n
-            else:
-                stats.requests.append(Request(t0, time.perf_counter(), n * per_window))
-                self.loaded[tenant] = Panel(tenant, m, 0, last, period * self.windows)
-                self.acked[m] = last
-                if last == self.windows - 1:
-                    self.period[m], self.acked[m] = period + 1, -1
-            m = (m + 1) % self.data.metrics
-
-
-CLIENTS = {"query": QueryClient, "ingest_many": IngestManyClient}
+def find_parts(bench: str, config: dict, traffic: dict) -> tuple:
+    """``(value model, client classes)`` that the configuration and the mix
+    name, from the files under ``bench``."""
+    model = load_part(bench, "values", config["values"]["distribution"])
+    kinds = [load_part(bench, "clients", spec["kind"]).Client for spec in traffic["clients"]]
+    for spec, kind in zip(traffic["clients"], kinds):
+        if kind.role not in ROLES:
+            raise ValueError(f"client kind {spec['kind']!r} has role {kind.role!r}, "
+                             f"not one of {ROLES}")
+    return model, kinds
 
 
 class Cell:
-    """One configuration under one traffic mix, made from one seed."""
+    """One configuration under one traffic mix, made from one seed, with
+    the value model and client classes of :func:`find_parts`."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int):
+    def __init__(self, config: dict, traffic: dict, seed: int, parts: tuple):
+        model, kinds = parts
+        specs = traffic["clients"]
         self.config = config
         self.traffic = traffic
         self.seed = seed
-        W = int(config["windows"])
         pre = traffic.get("preload", 0)
-        self.preload = W if pre == "all" else int(pre)
-        self.data = Data(config, traffic, seed)
-        specs = traffic["clients"]
-        self.clients = [
-            CLIENTS[spec["kind"]](spec, config, self.data, seed, i)
-            for i, spec in enumerate(specs)
-        ]
-        self.stats = [ClientStats(spec["kind"]) for spec in specs]
+        self.preload = int(config["windows"]) if pre == "all" else int(pre)
+        self.data = model.build(config, traffic, seed)
+        self.clients = [kind(spec, config, self.data, seed, i)
+                        for i, (kind, spec) in enumerate(zip(kinds, specs))]
+        self.stats = [ClientStats(spec["kind"], kind.role) for spec, kind in zip(specs, kinds)]
 
     # ---- set-up -----------------------------------------------------------
     def load(self, svc) -> None:
@@ -326,28 +227,18 @@ class Cell:
                 name, {w: self.data.window(m, w) for w in range(self.preload)}
             )
 
-    def warm(self, open_service, threads: int = 8) -> None:
+    def warm(self, open_service) -> None:
         """Compile every program the window will run, on throwaway services
-        so that the served one stays as loaded, its answer cache empty.
-        Ingest programs on one; query programs on another loaded as the
-        served one is (its arena planes have the same shapes), one batch
-        per reachable shape, several batches at once so their compiles
-        overlap."""
-        loaders = [c for c in self.clients if isinstance(c, IngestManyClient)]
-        if loaders:
-            with open_service() as scratch:
-                for c in loaders:
-                    c.warm(scratch)
-        batches = []
+        so that the served one stays as loaded, its answer cache empty."""
         for c in self.clients:
-            if isinstance(c, QueryClient):
-                batches += [(c.beta, b) for b in c.warm_batches()]
-        if batches:
-            with open_service() as scratch:
-                self.load(scratch)
-                with ThreadPoolExecutor(threads) as pool:
-                    for f in [pool.submit(scratch.query_many, b, beta=beta) for beta, b in batches]:
-                        f.result()
+            c.warm(open_service, self.load)
+
+    def lead_in(self, svc) -> None:
+        """The requests each client makes on the served service before the
+        window (its ``lead_in(svc)``, where its kind has one)."""
+        for c in self.clients:
+            if hasattr(c, "lead_in"):
+                c.lead_in(svc)
 
     # ---- the measured window ------------------------------------------------
     def run(self, svc, seconds: float, annotate) -> tuple[float, float]:
@@ -381,22 +272,17 @@ class Cell:
     def check_panels(self) -> tuple[list[Panel], list[Panel]]:
         """After the window: ``(full, sample)`` panels to ask the service.
 
-        ``full`` holds one panel per tenant loaded in the window over all
-        its acked windows (their mass must be all the acked values);
-        ``sample`` a seeded draw of ``check_answers`` panels inside them,
-        compared in full.  Only ingest cells ask: a read-only cell's answers
-        were kept in the window."""
-        full = [p for c in self.clients if isinstance(c, IngestManyClient)
-                for p in c.loaded.values()]
-        rng = seed_rng(self.seed, 4)
-        sample = []
+        ``full`` holds every client's ``check_panels()`` (their mass must be
+        all the values acked); ``sample`` a seeded draw of ``check_answers``
+        panels inside them, compared in full.  A read-only client checks
+        none: its answers were kept in the window."""
+        full = [p for c in self.clients for p in c.check_panels()]
+        return full, self.sample(full)
+
+    def sample(self, full: list[Panel]) -> list[Panel]:
+        """The seeded draw of ``check_answers`` panels inside ``full``."""
         n = int(self.traffic.get("check_answers", 32))
-        while len(sample) < n and full:
-            p = full[int(rng.integers(len(full)))]
-            lo = int(rng.integers(0, p.hi + 1))
-            hi = int(rng.integers(lo, p.hi + 1))
-            sample.append(dataclasses.replace(p, lo=lo, hi=hi))
-        return full, sample
+        return sample_panels(full, n, seed_rng(self.seed, 4))
 
     def kept_answers(self) -> list:
         """A seeded draw of ``check_answers`` answers the window kept."""

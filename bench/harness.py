@@ -1,12 +1,14 @@
 """Run one cell of ``BENCHMARK.json`` once and build its result line.
 
 Everything a cell is made of is found by name: the workload entry names its
-configuration (``configs`` → its ``file``) and its traffic mix
-(``bench/traffic/<traffic>.json``); every metric is a reader in
-``bench/metrics/<metric>.py``.  A reader defines ``read(run, before, after)``,
-which returns a number or ``None`` when it finds nothing to read, and may
-define ``snapshot(svc)``, which is called just before and just after the
-window and whose results it is given.
+configuration (``configs`` → its ``file``, which names its value model and
+its plain reference) and its traffic mix (``bench/traffic/<traffic>.json``,
+which names its client kinds; see ``generator``); every metric is a reader
+in ``bench/metrics/<metric>.py``.  A reader defines ``read(run, before,
+after)``, which returns a number or ``None`` when it finds nothing to read,
+and may define ``snapshot(svc)``, which is called just before and just after
+the window and whose results it is given.  End-to-end readers read the
+clients by role (:class:`Run`), so a new client kind reports them too.
 
 :func:`run_cell` is the whole run; ``bench/run.py`` is its command line.
 """
@@ -15,7 +17,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -44,14 +45,24 @@ class Run:
     cell: generator.Cell
     window_s: float
     setup_s: float
-    stats: dict[str, generator.ClientStats]  # by client kind
+    stats: list[generator.ClientStats]  # one a client, in the mix's order
     compiles: int  # programs lowered inside the window
     trace: trace_reduce.TraceSummary | None
     peaks: dict
 
-    def latencies(self, kind: str) -> list[float]:
-        s = self.stats.get(kind)
-        return [] if s is None else [r.latency for r in s.requests]
+    def clients(self, role: str) -> list[generator.ClientStats]:
+        """The stats of every client of ``role`` (``"query"`` or ``"ingest"``)."""
+        return [s for s in self.stats if s.role == role]
+
+    def requests(self, role: str) -> list[generator.Request]:
+        return [r for s in self.clients(role) for r in s.requests]
+
+    def latencies(self, role: str) -> list[float]:
+        return [r.latency for r in self.requests(role)]
+
+    def work(self, role: str) -> int:
+        """Panels answered (query) or values acked (ingest) in the window."""
+        return sum(r.work for r in self.requests(role))
 
 
 def p95(values) -> float | None:
@@ -72,17 +83,10 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
-def _module(path: str):
-    name = "bench_metric_" + os.path.basename(path)[:-3].replace(".", "_")
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def cell_parts(root: str, workload: str):
-    """``(workload entry, configuration, traffic, end-to-end metrics,
-    per-layer metrics)`` of the named cell, each metric with its reader."""
+    """``(workload entry, configuration, traffic, parts, end-to-end metrics,
+    per-layer metrics)`` of the named cell: ``parts`` is the value model and
+    client classes (``generator.find_parts``), each metric has its reader."""
     spec = load_spec(root)
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
@@ -91,6 +95,7 @@ def cell_parts(root: str, workload: str):
     configs = {c["name"]: c for c in spec["configs"]}
     config = _load_json(os.path.join(root, configs[w["config"]]["file"]))
     traffic = _load_json(os.path.join(root, "bench", "traffic", f"{w['traffic']}.json"))
+    parts = generator.find_parts(os.path.join(root, "bench"), config, traffic)
     e2e = [m for m in spec["end_to_end"] if workload in m.get("workloads", [workload])]
     reported = {m["name"] for m in e2e}
     layer = [
@@ -98,8 +103,9 @@ def cell_parts(root: str, workload: str):
         if workload in m.get("workloads", [workload]) and m["moves"] in reported
     ]
     for m in e2e + layer:
-        m["reader"] = _module(os.path.join(root, "bench", "metrics", f"{m['name']}.py"))
-    return w, config, traffic, e2e, layer
+        path = os.path.join(root, "bench", "metrics", f"{m['name']}.py")
+        m["reader"] = generator.load_module(path)
+    return w, config, traffic, parts, e2e, layer
 
 
 def _peaks(root: str, kind: str) -> dict:
@@ -144,17 +150,18 @@ def _arena_rows(svc) -> int:
 def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
              *, t0: float | None = None, platform: str = "tpu",
              cache: bool = True) -> tuple[dict, list[str]]:
-    """Run the cell once: load, warm up, measure for ``seconds``, compare.
+    """Run the cell once: load, warm up, lead in, measure for ``seconds``, compare.
 
-    Returns the result line (a dict) and the compared numbers, one line each
-    with its limit.  Raises :class:`NoDevice` before any work when JAX's
-    devices are not ``platform`` or fewer than the cell asks for.
+    Returns the result line (a dict) and lines for standard error, the
+    compared numbers last, each with its limit.  Raises before any work when
+    a name of the cell has no file, and :class:`NoDevice` when JAX's devices
+    are not ``platform`` or fewer than the cell asks for.
     ``platform`` and ``cache`` let the tests rehearse a run on the CPU
     without touching JAX's persistent compilation cache.
     """
     t0 = time.perf_counter() if t0 is None else t0
     sys.path.insert(0, os.path.join(root, "src"))
-    w, config, traffic, e2e, layer = cell_parts(root, workload)
+    w, config, traffic, parts, e2e, layer = cell_parts(root, workload)
     if cache:
         _configure_cache(root)
     import jax
@@ -167,11 +174,11 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
         )
     kind = devices[0].device_kind
     peaks = _peaks(root, kind)
-    reference = _module(os.path.join(root, config["reference"]))
+    reference = generator.load_module(os.path.join(root, config["reference"]))
     from repro.serve import HistogramService
 
     compiles = _CompileCounter(jax)
-    cell = generator.Cell(config, traffic, seed)
+    cell = generator.Cell(config, traffic, seed, parts)
     T, beta = int(config["T"]), int(config["beta"])
     readers = layer if trace else e2e
     work = tempfile.mkdtemp(prefix="bench-")
@@ -190,14 +197,15 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
 
         cell.load(svc)
         cell.warm(scratch_service)
-        before = {m["name"]: getattr(m["reader"], "snapshot", lambda s: None)(svc) for m in readers}
-        rows0 = _arena_rows(svc)
-        lowered0 = compiles.lowered
-        trace_dir = os.path.join(work, "trace")
         # set-up's objects live as long as the service: keep the collector
         # from walking them again inside the window
         gc.collect()
         gc.freeze()
+        cell.lead_in(svc)
+        before = {m["name"]: getattr(m["reader"], "snapshot", lambda s: None)(svc) for m in readers}
+        rows0 = _arena_rows(svc)
+        lowered0 = compiles.lowered
+        trace_dir = os.path.join(work, "trace")
         setup_s = time.perf_counter() - t0
         if trace:
             opts = jax.profiler.ProfileOptions()
@@ -250,12 +258,11 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
         readings.append({"mass_gap": abs(mass - n), "bad_bounds": 0.0, "err_over_eps": 0.0,
                          "eps_over_bound": eps / reference.eps_bound(p.lo, p.hi, per_window, T)})
     numbers = reference.worst(readings)
-    stats = {s.kind: s for s in cell.stats}
     attempted = sum(s.attempted for s in cell.stats)
     failed = sum(s.failed for s in cell.stats) + unanswered
     correct = bool(readings) and failed == 0 and reference.within(numbers)
 
-    run = Run(cell, t_end - t_start, setup_s, stats, in_window, summary, peaks)
+    run = Run(cell, t_end - t_start, setup_s, cell.stats, in_window, summary, peaks)
     metrics = {}
     for m in readers:
         value = m["reader"].read(run, before[m["name"]], after[m["name"]])
@@ -275,9 +282,10 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
         result["breakdown"] = trace_reduce.breakdown(summary)
     checks = {k: {"value": numbers[k], "limit": reference.LIMITS[k]} for k in reference.LIMITS}
     result["checks"] = checks
-    lines = [f"check {k}: {v['value']} (limit {v['limit']})" for k, v in checks.items()]
-    lines.append(f"answers checked: {len(readings)}; failed requests: {failed}; "
-                 f"programs lowered in the window: {in_window}; "
-                 f"arena capacity (floats) at the window's start and end: {rows0}, {rows1}")
+    lines = [f"answers checked: {len(readings)}; failed requests: {failed}; "
+             f"programs lowered in the window: {in_window}; "
+             f"arena capacity (floats) at the window's start and end: {rows0}, {rows1}"]
+    # the compared numbers, each beside its limit, last
+    lines += [f"check {k}: {v['value']} (limit {v['limit']})" for k, v in checks.items()]
     return result, lines
 

@@ -8,7 +8,7 @@ def snapshot(svc):
 
 
 def read(run, before, after):
-    s = run.stats.get("query")
-    if before is None or after is None or s is None or not s.requests:
+    batches = len(run.requests("query"))
+    if before is None or after is None or not batches:
         return None
-    return (after - before) / len(s.requests)
+    return (after - before) / batches

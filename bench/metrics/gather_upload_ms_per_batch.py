@@ -6,8 +6,8 @@ PROGRAM = "jit__gather_rows"
 
 
 def read(run, before, after):
-    s = run.stats.get("query")
-    if run.trace is None or s is None or not s.requests:
+    batches = len(run.requests("query"))
+    if run.trace is None or not batches:
         return None
     secs = run.trace.programs.get(PROGRAM, 0.0) + run.trace.h2d_s
-    return 1e3 * secs / len(s.requests)
+    return 1e3 * secs / batches

@@ -1,9 +1,8 @@
 """Raw values durably acked (WAL-fsynced and summarized) per second over the
-whole window."""
+whole window, by every ingest client."""
 
 
 def read(run, before, after):
-    s = run.stats.get("ingest_many")
-    if s is None or not s.requests:
+    if not run.requests("ingest"):
         return None
-    return s.work() / run.window_s
+    return run.work("ingest") / run.window_s

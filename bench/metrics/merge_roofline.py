@@ -15,8 +15,8 @@ def merge_bytes(cover_nodes: int, panels: int, T: int, beta: int) -> int:
 
 
 def read(run, before, after):
-    s = run.stats.get("query")
-    if run.trace is None or s is None or not s.distinct:
+    distinct = sum(s.distinct for s in run.clients("query"))
+    if run.trace is None or not distinct:
         return None
     if after["hits"] != before["hits"]:
         return None  # an answer-cache hit merges nothing: the count would be too high
@@ -24,7 +24,8 @@ def read(run, before, after):
     if secs <= 0:
         return None
     cfg = run.cell.config
-    least = merge_bytes(s.cover_nodes, s.distinct, int(cfg["T"]), int(cfg["beta"]))
+    nodes = sum(s.cover_nodes for s in run.clients("query"))
+    least = merge_bytes(nodes, distinct, int(cfg["T"]), int(cfg["beta"]))
     return 100.0 * least / run.peaks["hbm_bytes_per_s"] / secs
 
 

@@ -9,8 +9,7 @@ def snapshot(svc):
 
 
 def read(run, before, after):
-    s = run.stats.get("ingest_many")
-    calls = 0 if s is None else sum(1 for r in s.requests if r.work)
+    calls = sum(1 for r in run.requests("ingest") if r.work)
     if after is None or calls == 0 or "hist.pullup" not in after.spans:
         return None
     return 1e3 * after.self_s("hist.pullup", "hist.pullup.wait") / calls

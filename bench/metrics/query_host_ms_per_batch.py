@@ -9,7 +9,7 @@ def snapshot(svc):
 
 
 def read(run, before, after):
-    s = run.stats.get("query")
-    if after is None or s is None or not s.requests or "hist.query" not in after.spans:
+    batches = len(run.requests("query"))
+    if after is None or not batches or "hist.query" not in after.spans:
         return None
-    return 1e3 * after.self_s("hist.query", "hist.query.wait") / len(s.requests)
+    return 1e3 * after.self_s("hist.query", "hist.query.wait") / batches

@@ -1,5 +1,6 @@
-"""95th percentile of ``query_many`` batch latency over every batch of the
-window, from the moment the batch is sent until its answers are on the host."""
+"""95th percentile of query latency over every request of every query client
+in the window, from the request's start (when it was sent, or due) until
+its answers are on the host."""
 from harness import p95
 
 

@@ -1,8 +1,8 @@
-"""Panels answered (fresh, not degraded) per second over the whole window."""
+"""Panels answered (fresh, not degraded) per second over the whole window, by
+every query client."""
 
 
 def read(run, before, after):
-    s = run.stats.get("query")
-    if s is None or not s.requests:
+    if not run.requests("query"):
         return None
-    return s.work() / run.window_s
+    return run.work("query") / run.window_s

@@ -9,10 +9,9 @@ def snapshot(svc):
 
 
 def read(run, before, after):
-    s = run.stats.get("ingest_many")
-    if after is None or s is None or "hist.summarize" not in after.spans:
+    if after is None or "hist.summarize" not in after.spans:
         return None
-    windows = s.work() // int(run.cell.config["values_per_window"])
+    windows = run.work("ingest") // int(run.cell.config["values_per_window"])
     if windows == 0:
         return None
     return 1e3 * after.self_s("hist.summarize", "hist.summarize.wait") / windows

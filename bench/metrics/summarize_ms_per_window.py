@@ -5,10 +5,9 @@ PROGRAM = "jit_build_exact_padded_batched"
 
 
 def read(run, before, after):
-    s = run.stats.get("ingest_many")
-    if run.trace is None or s is None:
+    if run.trace is None:
         return None
-    windows = s.work() // int(run.cell.config["values_per_window"])
+    windows = run.work("ingest") // int(run.cell.config["values_per_window"])
     secs = run.trace.programs.get(PROGRAM, 0.0)
     if windows == 0 or secs <= 0:
         return None

@@ -11,9 +11,8 @@ clock as the device's program lines:
 - ``gaps``: the longest idle gaps, longest first, each labelled by the
   innermost span that covers its midpoint.
 
-Innermost is the shortest covering span, as in ``trace_reduce``; spans on
-one thread nest, so that is the deepest one.  ``trace_reduce``'s own
-gap labels read ``bench.*`` spans only.
+Innermost is the shortest covering span, as in ``trace_reduce``, whose
+gap labels these are.
 
 The harness removes the trace once it has reduced it, after the
 per-layer readers' closing ``snapshot``.  A reader that needs spans calls
@@ -31,9 +30,6 @@ import sys
 
 import trace_reduce
 
-PREFIXES = ("bench.", "hist.")
-
-
 @dataclasses.dataclass
 class SpanSummary:
     window_s: float
@@ -50,14 +46,6 @@ class SpanSummary:
         return sum(t for n, t in self.idle_by_span.items() if n.startswith(prefix))
 
 
-def _labels(events, w0: float, w1: float) -> list[trace_reduce.Event]:
-    return [
-        e for e in events
-        if e.name.startswith(PREFIXES) and e.name != trace_reduce.WINDOW_SPAN
-        and e.end > w0 and e.start < w1
-    ]
-
-
 def _idle(events, is_device, w0: float, w1: float) -> list[tuple[float, float]]:
     """Idle intervals of the first device inside ``[w0, w1]``."""
     dev = [e for e in events if is_device(e) and e.end > w0 and e.start < w1]
@@ -65,8 +53,7 @@ def _idle(events, is_device, w0: float, w1: float) -> list[tuple[float, float]]:
     busy = trace_reduce.union(
         (max(e.start, w0), min(e.end, w1)) for e in dev if e.plane == planes[0]
     ) if planes else []
-    edges = [w0] + [x for s, e in busy for x in (s, e)] + [w1]
-    return [(s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s]
+    return trace_reduce.idle(busy, w0, w1)
 
 
 def _attribute(idle, labels) -> dict[str, float]:
@@ -103,22 +90,17 @@ def reduce(events, is_device=trace_reduce.is_device_program, top: int = 10) -> S
     if not window:
         raise ValueError(f"no {trace_reduce.WINDOW_SPAN!r} span in the trace")
     w0, w1 = window[0].start, window[0].end
-    labels = _labels(events, w0, w1)
+    labels = trace_reduce.labels(events, w0, w1)
     spans: dict[str, float] = {}
     for e in labels:
         spans[e.name] = spans.get(e.name, 0.0) + min(e.end, w1) - max(e.start, w0)
     idle = _idle(events, is_device, w0, w1)
-    gaps = []
-    for s, e in sorted(idle, key=lambda g: g[0] - g[1])[:top]:
-        mid = (s + e) / 2
-        cover = [x for x in labels if x.start <= mid <= x.end]
-        gaps.append((min(cover, key=lambda x: x.end - x.start).name if cover else "host", e - s))
     return SpanSummary(
         window_s=w1 - w0,
         idle_s=sum(e - s for s, e in idle),
         spans=spans,
         idle_by_span=_attribute(idle, labels),
-        gaps=gaps,
+        gaps=trace_reduce.label_gaps(idle, labels, top),
     )
 
 

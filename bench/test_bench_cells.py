@@ -58,10 +58,10 @@ def test_cell_runs_and_is_correct(root, cell, trace):
 def test_same_seed_same_inputs(root):
     import generator
 
-    w, config, traffic, _, _ = harness.cell_parts(root, "logstats.planner")
-    a = generator.Cell(config, traffic, 5)
-    b = generator.Cell(config, traffic, 5)
-    c = generator.Cell(config, traffic, 6)
+    w, config, traffic, parts, _, _ = harness.cell_parts(root, "logstats.planner")
+    a = generator.Cell(config, traffic, 5, parts)
+    b = generator.Cell(config, traffic, 5, parts)
+    c = generator.Cell(config, traffic, 6, parts)
     assert (a.data.pooled(1, 0, 9) == b.data.pooled(1, 0, 9)).all()
     assert not (a.data.pooled(1, 0, 9) == c.data.pooled(1, 0, 9)).all()
     assert [a.clients[0].panel() for _ in range(20)] == [b.clients[0].panel() for _ in range(20)]

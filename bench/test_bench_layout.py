@@ -9,6 +9,7 @@ import shutil
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -51,6 +52,7 @@ def test_benchmark_json_is_well_formed(spec):
     for w in cells.values():
         assert w["config"] in configs and w["chips"] in (1, 4) and len(w["why"]) <= 200
         assert os.path.exists(os.path.join(BENCH, "traffic", f"{w['traffic']}.json"))
+        harness.cell_parts(REPO, w["name"])  # its value model and client kinds have files
         reported = [m for m in spec["end_to_end"] if w["name"] in m.get("workloads", [w["name"]])]
         assert "setup_s" in {m["name"] for m in reported} and len(reported) >= 2
         assert any(w["name"] in m["workloads"] for m in spec["per_layer"])
@@ -82,7 +84,8 @@ def test_new_config_mix_and_metric_are_found_as_files(tmp_path):
         json.dump({"preload": "all", "check_answers": 4, "clients": [
             {"kind": "query", "batch": 3, "span": {"choice": ["windows"]}}]}, f)
     with open(os.path.join(root, "bench", "metrics", "panels_in_window.py"), "w") as f:
-        f.write("def read(run, before, after):\n    return run.stats['query'].attempted\n")
+        f.write("def read(run, before, after):\n"
+                "    return sum(s.attempted for s in run.clients('query'))\n")
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         spec = json.load(f)
     spec["configs"].append({"name": "tiny-extra", "source": "a test", "reduced": [],
@@ -102,6 +105,145 @@ def test_new_config_mix_and_metric_are_found_as_files(tmp_path):
     assert result["metrics"]["panels_in_window"]["value"] == result["attempted"] > 0
     result, _ = harness.run_cell(root, "extra.scan", 9, 0.3, False, platform="cpu", cache=False)
     assert set(result["metrics"]) == {"query_p95_ms", "setup_s"}
+
+
+WALK = '''"""A clamped random walk on ``values.range``: ties at the clamps."""
+import numpy as np
+
+from generator import Windows
+
+
+def build(config, traffic, seed):
+    spec = config["values"]
+    per_window = int(config["values_per_window"])
+    lo, hi = spec["range"]
+
+    def draw(rng, n):
+        x = np.empty(n * per_window, np.float32)
+        v = rng.uniform(lo, hi)
+        for i, step in enumerate(rng.normal(0.0, spec["step"], x.size)):
+            v = x[i] = min(max(v + step, lo), hi)
+        return x.reshape(n, per_window)
+
+    return Windows(config, traffic, seed, draw)
+'''
+
+WRITER = '''"""An open loop writing the next window of each metric in turn at
+``rate`` windows a second through ``svc.record``, each ack timed from
+when its write was due."""
+import time
+
+from generator import Panel, Request
+
+
+class Client:
+    role = "ingest"
+
+    def __init__(self, spec, config, data, seed, index):
+        self.data = data
+        self.interval = 1.0 / float(spec["rate"])
+        self.first = int(config["windows"])  # the window after the preload
+        self.newest = {}  # metric -> its newest acked window
+
+    def warm(self, open_service, load):
+        with open_service() as scratch:
+            load(scratch)
+            for m, name in enumerate(self.data.names):
+                scratch.record(name, self.first, self.data.window(m, self.first))
+
+    def run(self, svc, t_end, annotate, stats):
+        due, n = time.perf_counter(), 0
+        while due < t_end:
+            time.sleep(max(0.0, due - time.perf_counter()))
+            m, w = n % self.data.metrics, self.first + n // self.data.metrics
+            stats.attempted += 1
+            with annotate("bench.record"):
+                svc.record(self.data.names[m], w, self.data.window(m, w))
+            stats.requests.append(Request(due, time.perf_counter(), self.data.per_window))
+            self.newest[m] = w
+            due, n = due + self.interval, n + 1
+
+    def check_panels(self):
+        return [Panel(self.data.names[m], m, 0, w) for m, w in self.newest.items()]
+'''
+
+
+def _add_cell(root, name, config, mix, e2e):
+    """Add a cell as a later change would: new files, and its entries in
+    ``BENCHMARK.json``."""
+    with open(os.path.join(root, "bench", "configs", f"{config['name']}.json"), "w") as f:
+        json.dump(config, f)
+    with open(os.path.join(root, "bench", "traffic", f"{name}.json"), "w") as f:
+        json.dump(mix, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": config["name"], "source": "a test", "reduced": [],
+                            "file": f"bench/configs/{config['name']}.json", "why": "a test"})
+    spec["workloads"].append({"name": f"extra.{name}", "config": config["name"],
+                              "traffic": name, "chips": 1, "why": "a test"})
+    for m in spec["end_to_end"]:
+        if m["name"] in e2e:
+            m["workloads"].append(f"extra.{name}")
+    # a latency that no cell reports end to end yet (the backfill's ack tail
+    # is per layer) comes in with the cell, read by its existing reader
+    for metric in sorted(set(e2e) - {m["name"] for m in spec["end_to_end"]}):
+        assert metric.endswith("_ms"), metric
+        spec["end_to_end"].append({"name": metric, "unit": "ms", "better": "lower", "bound": 0.1,
+                                   "source": "host_clock", "workloads": [f"extra.{name}"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    return f"extra.{name}"
+
+
+def _tiny_config(root, **changes):
+    with open(os.path.join(root, "bench", "configs", "tiny-daily.json")) as f:
+        cfg = json.load(f)
+    cfg.update(changes)
+    return cfg
+
+
+def test_new_value_model_and_client_kind_are_found_as_files(tmp_path):
+    root = tiny.make_root(str(tmp_path), "cpu")
+    with open(os.path.join(root, "bench", "values", "walk.py"), "w") as f:
+        f.write(WALK)
+    with open(os.path.join(root, "bench", "clients", "writer.py"), "w") as f:
+        f.write(WRITER)
+    cfg = _tiny_config(root, name="tiny-walk", metrics=2, windows=16,
+                       values={"distribution": "walk", "range": [0.0, 100.0], "step": 5.0})
+    cell = _add_cell(root, "live", cfg, {"preload": "all", "check_answers": 6, "clients": [
+        {"kind": "query", "batch": 4, "span": {"uniform": [1, 4]}},
+        {"kind": "writer", "rate": 40}]}, {"query_p95_ms", "ingest_ack_p95_ms"})
+    result, lines = harness.run_cell(root, cell, 2**31 + 5, 0.6, False, platform="cpu",
+                                     cache=False)
+    assert result["correct"], lines
+    assert result["failed"] == 0
+    got = result["metrics"]
+    assert set(got) == {"query_p95_ms", "ingest_ack_p95_ms", "setup_s"}
+    assert got["query_p95_ms"]["value"] > 0 and got["ingest_ack_p95_ms"]["value"] > 0
+    # the walk clamps: its values hold ties at both ends of the range
+    _w, config, traffic, parts, _e2e, _layer = harness.cell_parts(root, cell)
+    values = generator.Cell(config, traffic, 1, parts).data.pooled(0, 0, 15)
+    assert values.dtype == np.float32 and values.min() == 0.0 and values.max() == 100.0
+
+
+@pytest.mark.parametrize("part", ["distribution", "kind"])
+def test_an_unknown_part_fails_before_any_work(tmp_path, monkeypatch, part):
+    root = tiny.make_root(str(tmp_path), "cpu")
+    cfg = _tiny_config(root, name="tiny-odd")
+    mix = {"preload": "all", "clients": [{"kind": "query", "batch": 3,
+                                          "span": {"choice": [2]}}]}
+    if part == "distribution":
+        cfg["values"] = dict(cfg["values"], distribution="no-such-model")
+    else:
+        mix["clients"].append({"kind": "no-such-kind"})
+    cell = _add_cell(root, "odd", cfg, mix, {"query_p95_ms"})
+
+    def no_work(*args, **kwargs):
+        raise AssertionError("work began")
+
+    monkeypatch.setattr(generator.Cell, "__init__", no_work)
+    with pytest.raises(FileNotFoundError, match="no-such-"):
+        harness.run_cell(root, cell, 1, 0.1, False, platform="cpu", cache=False)
 
 
 def test_a_device_missing_from_the_peaks_table_fails(tmp_path):

@@ -10,6 +10,7 @@ import pytest
 BENCH = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, BENCH)
 
+import generator  # noqa: E402
 import harness  # noqa: E402
 import span_reduce as sr  # noqa: E402
 import trace_reduce as tr  # noqa: E402
@@ -33,8 +34,8 @@ def _query_events():
 def test_hist_spans_win_the_gap_label_over_an_enclosing_bench_span():
     t = sr.reduce(_query_events())
     assert t.gaps[0] == ("hist.query.wait", pytest.approx(0.3))
-    # trace_reduce's own label of that gap reads bench.* spans only
-    assert tr.reduce(_query_events()).gaps[0] == ("bench.query_many", pytest.approx(0.3))
+    # the gaps of trace_reduce, which the result line's breakdown carries, alike
+    assert tr.reduce(_query_events()).gaps == t.gaps
 
 
 def test_idle_is_cut_where_the_innermost_span_changes():
@@ -86,14 +87,15 @@ def test_recorded_cpu_trace_agrees_with_trace_reduce():
 
 
 def _reader(name):
-    return harness._module(os.path.join(BENCH, "metrics", f"{name}.py"))
+    return generator.load_module(os.path.join(BENCH, "metrics", f"{name}.py"))
 
 
 def _run(kind, requests, work=1):
-    reqs = [types.SimpleNamespace(work=work) for _ in range(requests)]
-    stats = types.SimpleNamespace(requests=reqs, work=lambda: work * requests)
+    """A run of one client of ``kind`` (``query`` or ``ingest_many``)."""
+    stats = generator.ClientStats(kind, "query" if kind == "query" else "ingest")
+    stats.requests = [generator.Request(0.0, 1.0, work) for _ in range(requests)]
     cell = types.SimpleNamespace(config={"values_per_window": 10})
-    return types.SimpleNamespace(stats={kind: stats}, cell=cell)
+    return harness.Run(cell, 1.0, 0.0, [stats], 0, None, {})
 
 
 def _summary(**spans):
@@ -132,3 +134,19 @@ def test_arena_upload_reader_reads_the_counter_per_batch():
     assert reader.read(_run("query", 4), None, None) is None
     svc = types.SimpleNamespace(registry=types.SimpleNamespace(cache_stats=lambda: {"hits": 0}))
     assert reader.snapshot(svc) is None
+
+
+def test_run_keeps_two_clients_of_one_kind_apart_and_reads_by_role():
+    q1, q2 = generator.ClientStats("query", "query"), generator.ClientStats("query", "query")
+    q1.requests = [generator.Request(0.0, 0.1, 3)]
+    q2.requests = [generator.Request(0.0, 0.3, 5), generator.Request(1.0, 1.2, 4)]
+    w = generator.ClientStats("writer", "ingest")
+    w.requests = [generator.Request(0.5, 0.55, 10)]
+    run = harness.Run(None, 2.0, 0.0, [q1, q2, w], 0, None, {})
+    assert run.clients("query") == [q1, q2] and run.clients("ingest") == [w]
+    assert run.work("query") == 12 and run.work("ingest") == 10
+    assert run.latencies("query") == pytest.approx([0.1, 0.3, 0.2])
+    assert _reader("query_panels_per_s").read(run, None, None) == 6.0
+    assert _reader("ingest_values_per_s").read(run, None, None) == 5.0
+    assert _reader("query_p95_ms").read(run, None, None) == pytest.approx(
+        1e3 * harness.p95([0.1, 0.3, 0.2]))

@@ -82,3 +82,24 @@ def test_reduce_recorded_cpu_trace():
     assert sum(g for _, g in t.gaps) <= t.window_s - t.busy_s + 1e-9
     # the recorder slept in bench.wait spans: the longest gaps are those
     assert t.gaps[0][0] == "bench.wait"
+
+
+def test_recorded_cpu_trace_gaps_are_labelled_by_the_innermost_hist_span():
+    events = tr.load_events(FIXTURE)
+    before = tr.reduce(events, is_device=_cpu_ops)
+    window = [e for e in events if e.name == tr.WINDOW_SPAN][0]
+    busy = tr.union((max(e.start, window.start), min(e.end, window.end))
+                    for e in events if _cpu_ops(e))
+    s, e = max(tr.idle(busy, window.start, window.end), key=lambda g: g[1] - g[0])
+    assert e - s == pytest.approx(before.gaps[0][1])
+    # the program's spans over that gap's middle, nested in the benchmark's span there
+    mid = (s + e) / 2
+    outer = min((x for x in events if x.name.startswith("bench.") and x.name != tr.WINDOW_SPAN
+                 and x.start <= mid <= x.end), key=lambda x: x.end - x.start)
+    a, b = max(s, outer.start), min(e, outer.end)
+    program = [tr.Event(outer.plane, outer.line, "hist.query", a, b),
+               tr.Event(outer.plane, outer.line, "hist.query.wait", (a + mid) / 2, (mid + b) / 2)]
+    t = tr.reduce(events + program, is_device=_cpu_ops)
+    assert t.gaps[0] == ("hist.query.wait", pytest.approx(e - s))
+    assert t.gaps[1:] == before.gaps[1:]
+    assert tr.breakdown(t)["idle_gaps"][0] == ["hist.query.wait", pytest.approx(e - s)]

@@ -26,7 +26,7 @@ TRAFFIC = {
     },
     "backfill": {
         "preload": 0, "value_pool": 16, "check_answers": 8,
-        "clients": [{"kind": "ingest_many", "windows_per_call": "windows"}],
+        "clients": [{"kind": "ingest_many", "windows_per_call": "windows", "lead_in_calls": 2}],
     },
 }
 CELLS = {

@@ -4,8 +4,10 @@ per-program device time, host transfer time and labelled idle gaps.
 The device lines are the ``XLA Modules`` lines of ``/device:*`` planes: one
 event per program run, named ``<program>(<fingerprint>)``.  The window is
 the benchmark's own ``bench.window`` host span; every interval is clipped
-to it.  Idle gaps are labelled by the innermost ``bench.*`` host span
-that covers the gap's midpoint (``host`` when none does).
+to it.  Idle gaps are labelled by the innermost host span, the
+benchmark's (``bench.*``) or the program's (``hist.*``), that covers the
+gap's midpoint (``host`` when none does): innermost is the shortest, and
+spans on one thread nest, so that is the deepest one.
 """
 from __future__ import annotations
 
@@ -15,6 +17,9 @@ import os
 import re
 
 WINDOW_SPAN = "bench.window"
+# host spans that name what the host was doing: the benchmark's own and
+# the program's (src/repro/core/spans.py)
+PREFIXES = ("bench.", "hist.")
 # host events that move arrays to the device (the PJRT TPU client's names)
 H2D_EVENTS = ("tpu::System::TransferToDevice", "XlaLinearize")
 
@@ -100,27 +105,42 @@ def reduce(events: list[Event], is_device=is_device_program, top: int = 10) -> T
         clip(e) for e in events
         if e.plane.startswith("/host:") and e.name in H2D_EVENTS and e.end > w0 and e.start < w1
     )
-    # idle gaps on the first device, labelled by what the benchmark was doing
-    u = busy_by_plane[0] if busy_by_plane else []
-    edges = [w0] + [x for s, e in u for x in (s, e)] + [w1]
-    labels = [e for e in events if e.name.startswith("bench.") and e.name != WINDOW_SPAN]
-    gaps = []
-    for s, e in zip(edges[::2], edges[1::2]):
-        if e <= s:
-            continue
-        mid = (s + e) / 2
-        cover = [x for x in labels if x.start <= mid <= x.end]
-        label = min(cover, key=lambda x: x.end - x.start).name if cover else "host"
-        gaps.append((label, e - s))
-    gaps.sort(key=lambda g: -g[1])
+    # idle gaps on the first device, labelled by what the host was doing
+    gaps = label_gaps(idle(busy_by_plane[0] if busy_by_plane else [], w0, w1),
+                      labels(events, w0, w1), top)
     return TraceSummary(
         window_s=w1 - w0,
         busy_s=busy,
         devices=len(planes),
         programs=programs,
         h2d_s=sum(e - s for s, e in h2d),
-        gaps=gaps[:top],
+        gaps=gaps,
     )
+
+
+def labels(events, w0: float, w1: float) -> list[Event]:
+    """The host spans (:data:`PREFIXES`) that overlap the window ``[w0, w1]``."""
+    return [
+        e for e in events
+        if e.name.startswith(PREFIXES) and e.name != WINDOW_SPAN and e.end > w0 and e.start < w1
+    ]
+
+
+def idle(busy: list[tuple[float, float]], w0: float, w1: float) -> list[tuple[float, float]]:
+    """The intervals of ``[w0, w1]`` outside ``busy`` (a :func:`union`)."""
+    edges = [w0] + [x for s, e in busy for x in (s, e)] + [w1]
+    return [(s, e) for s, e in zip(edges[::2], edges[1::2]) if e > s]
+
+
+def label_gaps(gaps, spans: list[Event], top: int) -> list[tuple[str, float]]:
+    """The ``top`` longest of ``gaps``, longest first, each labelled by the
+    innermost of ``spans`` that covers its midpoint (``host`` where none does)."""
+    out = []
+    for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:top]:
+        mid = (s + e) / 2
+        cover = [x for x in spans if x.start <= mid <= x.end]
+        out.append((min(cover, key=lambda x: x.end - x.start).name if cover else "host", e - s))
+    return out
 
 
 def breakdown(t: TraceSummary, top: int = 10) -> dict:
