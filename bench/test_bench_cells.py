@@ -26,7 +26,7 @@ def run(root, cell, trace, seed=2**31 + 11):
 
 
 @pytest.mark.parametrize("trace", [False, True], ids=["e2e", "traced"])
-@pytest.mark.parametrize("cell", sorted(tiny.CELLS))
+@pytest.mark.parametrize("cell", tiny.cells())
 def test_cell_runs_and_is_correct(root, cell, trace):
     result, lines = run(root, cell, trace)
     spec = harness.load_spec(root)

@@ -36,7 +36,7 @@ def test_broken_path_is_not_correct(root, monkeypatch, cell, fault):
     assert not result["correct"], lines
 
 
-@pytest.mark.parametrize("cell", sorted(tiny.CELLS))
+@pytest.mark.parametrize("cell", tiny.cells())
 def test_coarse_summaries_are_not_correct(root, monkeypatch, cell):
     faults.plant(monkeypatch, "coarse_summaries")
     result, lines = harness.run_cell(root, cell, 123, 0.5, False, platform="cpu", cache=False)

@@ -1,11 +1,12 @@
 """The existing cells' inputs are pinned: for ``logstats-daily`` and its tiny
-copy, the values the value model gives, and for the planner and the backfill
-the window's first three requests (after the lead-in), the panels checked
-after it and the warm-up, all bit for bit as the generator made them before
-value models and client kinds became files of their own (digests of that
-generator's output, seeds 7 and 3141592653).  The backfill's lead-in makes
-the loop's first calls in set-up, so its window opens on the calls that
-follow them: its window and checked panels are pinned with the lead-in."""
+copy (``tiny-daily``: its sizes under ``bench/rehearsal/``), the values the
+value model gives, and for the planner and the backfill the window's first
+three requests (after the lead-in), the panels checked after it and the
+warm-up, all bit for bit as the generator made them before value models and
+client kinds became files of their own (digests of that generator's output,
+seeds 7 and 3141592653).  The backfill's lead-in makes the loop's first
+calls in set-up, so its window opens on the calls that follow them: its
+window and checked panels are pinned with the lead-in."""
 import contextlib
 import hashlib
 import json
@@ -57,16 +58,16 @@ def digest(items) -> str:
 
 
 def config(name: str) -> dict:
-    with open(os.path.join(BENCH, "configs", "logstats-daily.json")) as f:
-        c = json.load(f)
-    if name != "logstats-daily":
-        c.update(tiny.CONFIGS[name], name=name)
-    return c
+    """``logstats-daily``, or as ``tiny-daily`` at its rehearsal sizes."""
+    if name == "tiny-daily":
+        return tiny.config("logstats-daily")
+    with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
+        return json.load(f)
 
 
 def traffic(name: str, small: bool) -> dict:
     if small:
-        return tiny.TRAFFIC[name]
+        return tiny.traffic(name)
     with open(os.path.join(BENCH, "traffic", f"{name}.json")) as f:
         return json.load(f)
 

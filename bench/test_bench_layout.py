@@ -16,6 +16,8 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
+import control  # noqa: E402
+import faults  # noqa: E402
 import generator  # noqa: E402
 import harness  # noqa: E402
 import tiny  # noqa: E402
@@ -75,7 +77,7 @@ def test_benchmark_json_is_well_formed(spec):
 
 def test_new_config_mix_and_metric_are_found_as_files(tmp_path):
     root = tiny.make_root(str(tmp_path), "cpu")
-    with open(os.path.join(root, "bench", "configs", "tiny-daily.json")) as f:
+    with open(os.path.join(root, "bench", "configs", "logstats-daily.json")) as f:
         cfg = json.load(f)
     cfg.update(name="tiny-extra", metrics=2, windows=24)
     with open(os.path.join(root, "bench", "configs", "tiny-extra.json"), "w") as f:
@@ -196,7 +198,7 @@ def _add_cell(root, name, config, mix, e2e):
 
 
 def _tiny_config(root, **changes):
-    with open(os.path.join(root, "bench", "configs", "tiny-daily.json")) as f:
+    with open(os.path.join(root, "bench", "configs", "logstats-daily.json")) as f:
         cfg = json.load(f)
     cfg.update(changes)
     return cfg
@@ -244,6 +246,88 @@ def test_an_unknown_part_fails_before_any_work(tmp_path, monkeypatch, part):
     monkeypatch.setattr(generator.Cell, "__init__", no_work)
     with pytest.raises(FileNotFoundError, match="no-such-"):
         harness.run_cell(root, cell, 1, 0.1, False, platform="cpu", cache=False)
+
+
+def _checkout(dest):
+    """A copy of this checkout's benchmark files, as a later change starts from."""
+    shutil.copytree(BENCH, dest / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), dest / "BENCHMARK.json")
+    os.symlink(os.path.join(REPO, "src"), dest / "src")
+    return str(dest)
+
+
+def _bench_files(source):
+    """Every file under ``source``'s ``bench/``, with its bytes."""
+    files = {}
+    for folder, _, names in os.walk(os.path.join(source, "bench")):
+        for name in names:
+            path = os.path.join(folder, name)
+            with open(path, "rb") as f:
+                files[os.path.relpath(path, source)] = f.read()
+    return files
+
+
+def _write_json(source, path, body):
+    with open(os.path.join(source, path), "w") as f:
+        json.dump(body, f)
+
+
+def test_a_cell_brought_as_new_files_gets_its_rehearsal(tmp_path, monkeypatch):
+    """The contract's guard: a cell that brings a configuration, a value
+    model, a client kind, a mix and its two rehearsal files, with its
+    entries in ``BENCHMARK.json``, is rehearsed, controlled and faulted
+    with no edit to any file the benchmark has."""
+    source = _checkout(tmp_path / "source")
+    before = _bench_files(source)
+    with open(os.path.join(source, "bench", "values", "walk.py"), "w") as f:
+        f.write(WALK)
+    with open(os.path.join(source, "bench", "clients", "writer.py"), "w") as f:
+        f.write(WRITER)
+    cfg = _tiny_config(source, name="fleet-walk", metrics=10, windows=1440,
+                       values_per_window=24000,
+                       values={"distribution": "walk", "range": [0.0, 100.0], "step": 1.0})
+    cell = _add_cell(source, "live", cfg, {"preload": "all", "check_answers": 32, "clients": [
+        {"kind": "query", "batch": 64, "span": {"uniform": [1, 60]}},
+        {"kind": "writer", "rate": 100}]}, {"query_p95_ms", "ingest_ack_p95_ms"})
+    _write_json(source, "bench/rehearsal/configs/fleet-walk.json", {
+        "metrics": 2, "windows": 16, "values_per_window": 256, "beta": 8, "T": 64,
+        "values": {"distribution": "walk", "range": [0.0, 100.0], "step": 5.0}})
+    _write_json(source, "bench/rehearsal/traffic/live.json", {
+        "preload": "all", "check_answers": 6, "clients": [
+            {"kind": "query", "batch": 4, "span": {"uniform": [1, 4]}},
+            {"kind": "writer", "rate": 40}]})
+    after = _bench_files(source)
+    assert {p: after[p] for p in before} == before and len(after) == len(before) + 6
+    assert cell in tiny.cells(source)
+
+    root = tiny.make_root(str(tmp_path / "tiny"), "cpu", source)
+    _w, config, traffic, _parts, _e2e, _layer = harness.cell_parts(root, cell)
+    assert config["name"] == "fleet-walk" and config["values_per_window"] == 256
+    assert traffic["clients"][1] == {"kind": "writer", "rate": 40}
+    result, lines = harness.run_cell(root, cell, 2**31 + 7, 0.6, False, platform="cpu",
+                                     cache=False)
+    assert result["correct"], lines
+    assert set(result["metrics"]) == {"query_p95_ms", "ingest_ack_p95_ms", "setup_s"}
+    r = control.readings(root, cell, 2**31 + 7)
+    assert r["control_fails"] and r["reference_passes"], r
+    faults.plant(monkeypatch, "coarse_summaries")
+    result, lines = harness.run_cell(root, cell, 123, 0.5, False, platform="cpu", cache=False)
+    assert not result["correct"], lines
+
+
+@pytest.mark.parametrize("folder", ["configs", "traffic"])
+def test_a_cell_without_its_rehearsal_files_fails_in_make_root(tmp_path, folder):
+    source = _checkout(tmp_path / "source")
+    mix = {"preload": "all", "clients": [{"kind": "query", "batch": 3, "span": {"choice": [2]}}]}
+    _add_cell(source, "bare", _tiny_config(source, name="daily-bare"), mix, {"query_p95_ms"})
+    brought = {"configs": ("daily-bare", {"windows": 8}), "traffic": ("bare", mix)}
+    other = "traffic" if folder == "configs" else "configs"
+    name, body = brought[other]
+    _write_json(source, f"bench/rehearsal/{other}/{name}.json", body)
+    missing = f"bench/rehearsal/{folder}/{brought[folder][0]}.json"
+    with pytest.raises(FileNotFoundError, match=re.escape(missing)):
+        tiny.make_root(str(tmp_path / "tiny"), "cpu", source)
+    assert not os.path.exists(tmp_path / "tiny")
 
 
 def test_a_device_missing_from_the_peaks_table_fails(tmp_path):

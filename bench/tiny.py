@@ -1,9 +1,23 @@
 """A tiny copy of the benchmark for rehearsals on the CPU.
 
-:func:`make_root` lays out a checkout in a directory: this benchmark's own
-files, the program's ``src`` (linked), and a ``BENCHMARK.json`` whose cells
-are the real ones at tiny sizes — the same traffic kinds and readers, a few
-metrics of a few windows of 256 values, β = 8.
+A cell's rehearsal is data the cell brings as files of its own, found by
+name as its value model and client kinds are:
+
+- ``bench/rehearsal/configs/<config>.json``: overrides of the cell's
+  configuration file at tiny sizes (``metrics``, ``windows``,
+  ``values_per_window``, ``beta``, ``T``, and the value model's keys).  Each
+  key replaces the configuration's key of that name whole, so a nested
+  group such as ``values`` is given whole.
+- ``bench/rehearsal/traffic/<traffic>.json``: the cell's mix at tiny
+  sizes, whole.
+
+:func:`make_root` lays out a checkout in a directory from a source
+checkout: its benchmark files, its ``src`` (linked), and its
+``BENCHMARK.json`` as it stands, every cell's configuration and mix file
+replaced by its tiny form.  A cell without its rehearsal files fails there,
+before any work, naming the missing path.  :func:`cells` lists every cell,
+for the tests that rehearse each one; a cell added as new files and
+``BENCHMARK.json`` entries gets its rehearsal with no edit here.
 """
 from __future__ import annotations
 
@@ -14,57 +28,60 @@ import shutil
 BENCH = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(BENCH)
 
-CONFIGS = {
-    "tiny-daily": {"metrics": 4, "windows": 64, "values_per_window": 256, "beta": 8, "T": 64,
-                   "values": {"distribution": "gumbel", "loc": [0.0, 100.0],
-                              "scale": [0.5, 20.0]}},
-}
-TRAFFIC = {
-    "planner": {
-        "preload": "all", "check_answers": 8,
-        "clients": [{"kind": "query", "batch": 12, "span": {"uniform": [1, "windows"]}}],
-    },
-    "backfill": {
-        "preload": 0, "value_pool": 16, "check_answers": 8,
-        "clients": [{"kind": "ingest_many", "windows_per_call": "windows", "lead_in_calls": 2}],
-    },
-}
-CELLS = {
-    "logstats.planner": ("tiny-daily", "planner"),
-    "logstats.backfill": ("tiny-daily", "backfill"),
-}
+
+def _load(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
 
 
-def make_root(dest: str, kind: str) -> str:
-    """Write a tiny checkout under ``dest`` for devices of ``kind`` and return it."""
-    root = os.path.join(dest, "checkout")
-    shutil.copytree(BENCH, os.path.join(root, "bench"),
-                    ignore=shutil.ignore_patterns("__pycache__", "test_*"))
-    os.symlink(os.path.join(REPO, "src"), os.path.join(root, "src"))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        spec = json.load(f)
-    full = {c["name"]: c for c in spec["configs"]}
-    configs = []
-    for name, sizes in CONFIGS.items():
-        base = "logstats-daily"
-        with open(os.path.join(REPO, full[base]["file"])) as f:
-            cfg = json.load(f)
-        cfg.update(sizes, name=name)
-        path = f"bench/configs/{name}.json"
-        with open(os.path.join(root, path), "w") as f:
-            json.dump(cfg, f)
-        configs.append({**full[base], "name": name, "file": path})
-    for name, mix in TRAFFIC.items():
-        with open(os.path.join(root, "bench", "traffic", f"{name}.json"), "w") as f:
-            json.dump(mix, f)
+def _spec(source: str) -> dict:
+    return _load(os.path.join(source, "BENCHMARK.json"))
+
+
+def cells(source: str = REPO) -> list[str]:
+    """The names of the cells in ``source``'s ``BENCHMARK.json``, sorted."""
+    return sorted(w["name"] for w in _spec(source)["workloads"])
+
+
+def _rehearsal(source: str, folder: str, name: str) -> dict:
+    path = os.path.join("bench", "rehearsal", folder, f"{name}.json")
+    if not os.path.isfile(os.path.join(source, path)):
+        raise FileNotFoundError(f"no rehearsal file {path} for {name!r}")
+    return _load(os.path.join(source, path))
+
+
+def config(name: str, source: str = REPO) -> dict:
+    """The configuration ``name`` of ``source`` at its rehearsal sizes."""
+    entry = next(c for c in _spec(source)["configs"] if c["name"] == name)
+    cfg = _load(os.path.join(source, entry["file"]))
+    cfg.update(_rehearsal(source, "configs", name))
+    return cfg
+
+
+def traffic(name: str, source: str = REPO) -> dict:
+    """The mix ``name`` of ``source`` at its rehearsal sizes."""
+    return _rehearsal(source, "traffic", name)
+
+
+def make_root(dest: str, kind: str, source: str = REPO) -> str:
+    """Write a tiny copy of the checkout ``source`` under ``dest`` for
+    devices of ``kind`` and return it."""
+    spec = _spec(source)
+    files = {c["name"]: c["file"] for c in spec["configs"]}
+    tiny = {}  # path in the checkout -> its tiny body, every one read before any work
     for w in spec["workloads"]:
-        w["config"], w["traffic"] = CELLS[w["name"]]
-    spec["configs"] = configs
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(spec, f)
+        tiny[files[w["config"]]] = config(w["config"], source)
+        tiny[os.path.join("bench", "traffic", f"{w['traffic']}.json")] = traffic(w["traffic"], source)
+    root = os.path.join(dest, "checkout")
+    shutil.copytree(os.path.join(source, "bench"), os.path.join(root, "bench"),
+                    ignore=shutil.ignore_patterns("__pycache__", "test_*"))
+    os.symlink(os.path.join(source, "src"), os.path.join(root, "src"))
+    shutil.copy(os.path.join(source, "BENCHMARK.json"), os.path.join(root, "BENCHMARK.json"))
+    for path, body in tiny.items():
+        with open(os.path.join(root, path), "w") as f:
+            json.dump(body, f)
     peaks_path = os.path.join(root, "bench", "peaks.json")
-    with open(peaks_path) as f:
-        peaks = json.load(f)
+    peaks = _load(peaks_path)
     peaks[kind] = dict(peaks["TPU v5 lite"], source="a rehearsal's stand-in")
     with open(peaks_path, "w") as f:
         json.dump(peaks, f)
