@@ -37,11 +37,12 @@ remain (its main ``while`` loop).
 and bit-exactly tested against the sequential reference): because ``A`` is
 non-decreasing, the sweep's cut for target ``t_j = j·N/β`` is exactly
 
-    cut_j = searchsorted(A, t_j, side='right')
+    cut_j = #{m : A[m] ≤ t_j}  (= searchsorted(A, t_j, side='right'))
     b*_j  = pos[cut_j]                       (interior boundaries, j=1..β-1)
     S*_j  = A[cut_j - 1]                     (cumulative size at the cut)
 
-so the whole merge is one sort + one cumsum + one batched binary search:
+so the whole merge is one sort + one cumsum + one batched count, taken in
+two levels over blocks of ``A`` (``_count_le``) with no loop:
 ``O(kT log kT)`` work at ``O(log)`` depth instead of the paper's ``O(kT)``
 sequential loop.  Output is identical (see tests/test_merge_equivalence.py).
 
@@ -287,13 +288,42 @@ def pre_histogram(histograms: Histogram) -> tuple[jax.Array, jax.Array]:
     return pos, cum[:-1]
 
 
+# Width of a block of ``A`` in the two-level count: one lane row of a TPU
+# vector register.
+_CUT_BLOCK = 128
+
+
+def _count_le(A: jax.Array, targets: jax.Array) -> jax.Array:
+    """``#{m : A[m] ≤ t}`` for each target ``t``, for a non-decreasing ``A``.
+
+    This is ``searchsorted(A, targets, side="right")`` without its binary
+    search, which lowers to a ``while`` loop of one scalar gather a step.
+    ``A`` is padded with ``+inf`` to whole blocks of ``_CUT_BLOCK``; a
+    target's block is the last whose first element is ≤ it, found by
+    counting the block heads, and its cut is that block's offset plus a
+    count inside the one block row it gathers.  Exact under ties: every
+    earlier block is ≤ its successor's head ≤ t, every later head is > t.
+    Both counts use ``searchsorted``'s own comparator (``method=
+    "compare_all"``), so ±0 and NaN order as in the binary search.
+    """
+    L = A.shape[0]
+    B = _CUT_BLOCK
+    rows = jnp.pad(A, (0, -L % B), constant_values=jnp.inf).reshape(-1, B)
+    count = functools.partial(jnp.searchsorted, side="right", method="compare_all")
+    block = jnp.maximum(count(rows[:, 0], targets) - 1, 0)
+    within = jax.vmap(count)(rows[block], targets)
+    # only a NaN target counts the +inf padding
+    return jnp.minimum(block * B + within, L)
+
+
 @functools.partial(jax.jit, static_argnames=("beta",))
 def merge(histograms: Histogram, beta: int) -> Histogram:
     """Merge ``k`` stacked ``T``-bucket summaries into a β-bucket histogram.
 
     Vectorized rank-select equivalent of paper Algorithm 1 (see module
     docstring).  Fully jit-able: one sort of the boundaries that carries
-    the masses along + cumsum + batched searchsorted.
+    the masses along + cumsum + a count of ``A ≤ t_j`` for each of the β-1
+    targets (``_count_le``: block heads, then one block row), with no loop.
     """
     # named scopes tag the device ops of each phase in a profiler trace
     with jax.named_scope("merge.presort"):
@@ -301,7 +331,7 @@ def merge(histograms: Histogram, beta: int) -> Histogram:
     with jax.named_scope("merge.cut"):
         n = jnp.sum(histograms.sizes)
         targets = jnp.arange(1, beta, dtype=A.dtype) * (n / beta)
-        cut = jnp.searchsorted(A, targets, side="right")  # (β-1,) in [0, len(A)]
+        cut = _count_le(A, targets)  # (β-1,) in [0, len(A)]
         interior = pos[cut]
         boundaries = jnp.concatenate([pos[:1], interior, pos[-1:]])
         # Cumulative size at each cut: A[cut-1], with A[-1] treated as 0.

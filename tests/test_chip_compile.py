@@ -113,6 +113,13 @@ def _no_pre_histogram_gather(hlo_text: str) -> None:
     assert PRE_HISTOGRAM not in sizes, sizes
 
 
+def _no_while(hlo_text: str) -> None:
+    # the cut is a count with no binary search: a `while` would be the
+    # searchsorted loop of one scalar gather a step
+    loops = [line for line in hlo_text.splitlines() if re.search(r"\bwhile\b", line)]
+    assert not loops, loops[:3]
+
+
 def test_query_merge_compiles_for_v5e(one_chip):
     compiled = merge_stacks.lower(
         _spec(one_chip, (PANELS, K_PAD, T + 1)),
@@ -121,16 +128,29 @@ def test_query_merge_compiles_for_v5e(one_chip):
     ).compile()
     _fits(compiled)
     _no_pre_histogram_gather(compiled.as_text())
+    _no_while(compiled.as_text())
+
+
+def test_planner_merge_compiles_for_v5e_without_a_loop(one_chip):
+    # the benchmark planner's merge shape: 256 panels of day ranges within
+    # one month, each of at most 8 canonical nodes
+    compiled = merge_stacks.lower(
+        _spec(one_chip, (PANELS, 8, T + 1)),
+        _spec(one_chip, (PANELS, 8, T)),
+        beta=BETA,
+    ).compile()
+    _no_while(compiled.as_text())
 
 
 def test_query_merge_lowers_without_pre_histogram_gather():
-    # the same check on the portable lowering, where no v5e can be described
+    # the same checks on the portable lowering, where no v5e can be described
     lowered = merge_stacks.lower(
         jax.ShapeDtypeStruct((PANELS, K_PAD, T + 1), jnp.float32),
         jax.ShapeDtypeStruct((PANELS, K_PAD, T), jnp.float32),
         beta=BETA,
     )
     _no_pre_histogram_gather(lowered.as_text())
+    _no_while(lowered.as_text())
 
 
 def test_arena_gather_compiles_for_v5e(one_chip):

@@ -175,3 +175,127 @@ def test_one_sort_pre_histogram_is_bit_identical(features, Q, seed):
         one = merge(Histogram(b[q], s[q]), beta)
         assert np.array_equal(_bits(one.boundaries), _bits(got[0][q]))
         assert np.array_equal(_bits(one.sizes), _bits(got[1][q]))
+
+
+# ---------------------------------------------------------------------------
+# Bit identity of the two-level counted cut against the binary search
+# ---------------------------------------------------------------------------
+
+
+def _searchsorted_cut(A, targets):
+    """The cut by binary search — the form the two-level count replaced,
+    kept here as the oracle."""
+    return jnp.searchsorted(A, targets, side="right")
+
+
+_BLOCK = histogram._CUT_BLOCK
+
+
+def _crafted_cdf(case, L, rng):
+    """A non-decreasing ``A`` of length ``L`` and targets that hit the
+    requested hard case."""
+    if case == "all_zero_mass":
+        return np.zeros(L, np.float32), np.zeros(7, np.float32)
+    mass = rng.integers(0, 4, size=L).astype(np.float32)
+    if case == "tied_runs":
+        # long runs of zero mass, some across block edges
+        mass[rng.random(L) < 0.9] = 0.0
+    A = np.cumsum(mass, dtype=np.float32)
+    n = float(A[-1])
+    beta = int(rng.integers(2, 300))
+    even = np.arange(1, beta, dtype=np.float32) * np.float32(n / beta)
+    if case == "target_equals_A":
+        return A, np.concatenate([even, A[rng.integers(0, L, size=64)]])
+    if case == "signed_zero_and_nan":
+        # the binary search's total order: -0.0 before 0.0, NaN after +inf
+        odd = np.array([-np.inf, -0.0, 0.0, np.inf, np.nan], np.float32)
+        return A, np.concatenate([even, odd])
+    if case == "block_edge":
+        # targets at, just under and between the values either side of
+        # every block edge, so that cuts fall on a multiple of the block
+        edge = np.arange(_BLOCK, L, _BLOCK)
+        lo, hi = A[edge - 1], A[edge]
+        mid = lo + (hi - lo) / 2
+        return A, np.concatenate(
+            [even, lo, hi, mid, np.nextafter(hi, np.float32(-np.inf))]
+        ).astype(np.float32)
+    return A, even
+
+
+@pytest.mark.parametrize("L", [1, _BLOCK - 1, _BLOCK, _BLOCK + 1, 4065, 16263])
+@pytest.mark.parametrize(
+    "case",
+    [
+        "tied_runs",
+        "all_zero_mass",
+        "target_equals_A",
+        "block_edge",
+        "signed_zero_and_nan",
+    ],
+)
+def test_counted_cut_equals_searchsorted(case, L):
+    # 4065 and 16263 are len(A) of the pull-up (k 2) and the planner
+    # (k_pad 8) at T 2,032: neither is a multiple of the block
+    A, targets = _crafted_cdf(case, L, np.random.default_rng(L))
+    A, targets = jnp.asarray(A), jnp.asarray(targets)
+    got = jax.jit(histogram._count_le)(A, targets)
+    want = jax.jit(_searchsorted_cut)(A, targets)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+_SERVED_T = 2032
+
+
+def _served_stack(features, Q, k_pad, seed):
+    """``(Q, k_pad, T+1)``/``(Q, k_pad, T)`` exact summaries at the served
+    width T = 2,032, with the requested hard cases."""
+    rng = np.random.default_rng(seed)
+    T = _SERVED_T
+    B = np.empty((Q, k_pad, T + 1), np.float32)
+    S = np.empty((Q, k_pad, T), np.float32)
+    for q in range(Q):
+        k = int(rng.integers(1, k_pad + 1)) if "pad_rows" in features else k_pad
+        for j in range(k):
+            if "unit_sizes" in features:
+                # one value a bucket: every A is an integer and every
+                # target j·N/β lands on one
+                n = T
+            else:
+                n = int(rng.integers(T, 3 * T))
+            if "ties" in features:
+                v = rng.integers(-40, 40, size=n).astype(np.float32)
+            else:
+                v = rng.gumbel(size=n).astype(np.float32)
+            cuts = histogram._cut_indices(n, T)
+            B[q, j] = np.sort(v)[np.minimum(cuts, n - 1)]
+            S[q, j] = np.diff(cuts)
+        B[q, k:] = B[q, 0]  # pad rows as _gather_rows makes them
+        S[q, k:] = S[q, 0] * np.float32(0.0)
+    if "all_zero_mass" in features:
+        S[0] = 0.0
+    return B, S
+
+
+@pytest.mark.parametrize(
+    "k_pad, beta",
+    [(8, 254), (2, _SERVED_T)],
+    ids=["planner", "pullup"],
+)
+@pytest.mark.parametrize(
+    "features",
+    [("pad_rows",), ("ties",), ("all_zero_mass",), ("unit_sizes",)],
+    ids="+".join,
+)
+def test_counted_cut_merge_is_bit_identical(features, k_pad, beta):
+    B, S = _served_stack(features, 2, k_pad, seed=17)
+    b, s = jnp.asarray(B), jnp.asarray(S)
+    got = merge_stacks(b, s, beta=beta)
+    with mock.patch.object(histogram, "_count_le", _searchsorted_cut):
+        want = jax.jit(
+            jax.vmap(lambda b, s: merge.__wrapped__(Histogram(b, s), beta))
+        )(b, s)
+    assert np.array_equal(_bits(got[0]), _bits(want.boundaries))
+    assert np.array_equal(_bits(got[1]), _bits(want.sizes))
+    one = merge(Histogram(b[1], s[1]), beta)
+    assert np.array_equal(_bits(one.boundaries), _bits(want.boundaries[1]))
+    assert np.array_equal(_bits(one.sizes), _bits(want.sizes[1]))
